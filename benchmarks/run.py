@@ -4,10 +4,13 @@ roofline/kernel reports. Prints ``name,us_per_call,derived`` CSV.
   PYTHONPATH=src python -m benchmarks.run [--full] [--only table1,...]
                                           [--devices N] [--profile]
 
-``--devices N`` forces N fake XLA host devices (CPU) BEFORE the first JAX
-import, so the sharded sweep paths (``repro.dist``) are runnable on
-CPU-only machines and CI; harnesses pick the debug mesh up via
-``repro.dist.auto_grid_mesh``.
+``--devices N`` FORCES THE CPU BACKEND (``JAX_PLATFORMS=cpu``) with N fake
+XLA host devices, set before the first JAX import: a CPU rehearsal of the
+sharded sweep paths (``repro.dist``) for CPU-only machines and CI.
+Harnesses pick the debug mesh up via ``repro.dist.auto_grid_mesh``. No chip
+run uses it; on an accelerator host the harnesses see the real devices.
+
+The persistent compilation cache goes where ``repro.compile_cache`` says.
 
 ``--profile`` runs the obs-instrumented variant (``repro.obs``): a run
 manifest (backend, devices, XLA flags, config hash) is written to
@@ -50,8 +53,9 @@ def main(argv=None) -> None:
     ap.add_argument("--full", action="store_true", help="paper-scale rounds")
     ap.add_argument("--only", default="", help="comma-separated harness names")
     ap.add_argument("--devices", type=int, default=0,
-                    help="force N fake XLA host devices (before JAX import) "
-                    "so sharded benchmarks run on CPU-only machines")
+                    help="force the CPU backend with N fake XLA host devices "
+                    "(before JAX import): a CPU rehearsal of the sharded "
+                    "benchmarks, never a chip run")
     ap.add_argument("--profile", action="store_true",
                     help="obs-instrumented run: BENCH_manifest.json, event "
                     "log, and a cold/warm phase per harness (each runs twice)")
@@ -60,6 +64,9 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     if args.devices:
         _force_host_devices(args.devices)
+    from repro import compile_cache
+
+    compile_cache.enable()
 
     from benchmarks import (
         ablation_selection, analysis_audit, appj1_large_k, comm_frontier,

@@ -86,13 +86,16 @@ class FedAvg(base.FederatedAlgorithm):
             from repro.kernels.aggregate import ops as agg_ops
 
             if comm_cfg.ef_enabled(comm) and agg_ops.use_fused_aggregate():
-                # fused EF round: the wire deltas C(y_i − x) aggregate and
-                # apply in one kernel pass — x + lr·meanᵢwᵢĉᵢ expressed as
-                # x − (−lr)·Σᵢ(wᵢ/S)·ĉᵢ (meanᵢwᵢ = 1 by construction of the
-                # participation scale, so this equals the unfused
-                # reconstruct-then-lerp to float tolerance)
+                # fused EF round: the wire deltas ĉᵢ = C(y_i − x_start)
+                # aggregate and apply in one kernel pass. The unfused
+                # reconstruct-then-lerp is x + lr·(x_start + meanᵢwᵢĉᵢ − x);
+                # meanᵢwᵢ = 1 by construction of the participation scale, so
+                # it equals lerp(lr, x, x_start) − (−lr)·Σᵢ(wᵢ/S)·ĉᵢ to float
+                # tolerance (x_start ≠ x under a lossy downlink; the lerp is
+                # exactly x under an identity one)
                 x, comm = comm_lib.uplink_fused_apply(
-                    comm, y_final, cids, comm_lib.comm_key(key), state.x,
+                    comm, y_final, cids, comm_lib.comm_key(key),
+                    tm.tree_lerp(self.server_lr, state.x, x_start),
                     -self.server_lr, ref=x_start)
             else:
                 y_hat, comm = comm_lib.uplink(

@@ -56,6 +56,30 @@ def shuffled_heterogeneity(
     return client_x, client_y
 
 
+def pathological_shards(
+    features: np.ndarray,  # [n, ...]
+    labels: np.ndarray,  # [n]
+    *,
+    num_clients: int,
+    shards_per_client: int = 2,
+    seed: int = 0,
+):
+    """The pathological non-IID split of McMahan et al. 2017
+    (arXiv:1602.05629, §3): sort the samples by label, cut them into
+    ``num_clients · shards_per_client`` equal shards and deal each client
+    ``shards_per_client`` shards at random (MNIST: 200 shards of 300, two
+    per client, 100 clients × 600). Returns (client_features [N, n_i, ...],
+    client_labels [N, n_i]); a remainder of fewer than one shard is dropped.
+    """
+    rng = np.random.default_rng(seed)
+    n_shards = num_clients * shards_per_client
+    size = len(labels) // n_shards
+    order = np.argsort(labels, kind="stable")[: n_shards * size]
+    deal = rng.permutation(n_shards).reshape(num_clients, shards_per_client)
+    idx = order.reshape(n_shards, size)[deal].reshape(num_clients, -1)
+    return features[idx], labels[idx]
+
+
 def dirichlet_partition(labels: np.ndarray, *, num_clients: int, alpha: float, seed: int = 0):
     """Standard Dirichlet(α) label-skew partition; returns index lists."""
     rng = np.random.default_rng(seed)

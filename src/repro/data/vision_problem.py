@@ -65,9 +65,20 @@ def vision_spec(
     cx, cy = partition.shuffled_heterogeneity(
         data, homogeneous_frac=homogeneous_frac, num_clients=num_clients,
         seed=seed)
-    features = jnp.asarray(cx)  # [N, n_i, d]
-    labels = jnp.asarray(cy, jnp.int32)
-    n_clients, n_per, d = features.shape
+    return vision_spec_from_shards(
+        key, cx, cy, num_classes=num_classes, hidden=hidden, batch=batch,
+        l2=l2, name=name)
+
+
+def vision_spec_from_shards(key, features, labels, *, num_classes: int,
+                            hidden: int, batch: int, l2: float = 1e-4,
+                            name: str = "vision") -> ProblemSpec:
+    """A ``vision``-family spec over pre-partitioned shards: features
+    [N, n_i, d], integer labels [N, n_i]; an MLP d-hidden-classes (no hidden
+    layer when ``hidden`` is 0) initialized from ``key``."""
+    features = jnp.asarray(features)
+    labels = jnp.asarray(labels, jnp.int32)
+    n_clients, _, d = features.shape
     dims = (d, hidden, num_classes) if hidden else (d, num_classes)
 
     x0 = _mlp_init(key, dims)

@@ -58,11 +58,9 @@ same-shaped grid on the same mesh reuses the compile.
 Entry points: ``core.sweep.run_sweep(..., mesh=...)`` and
 ``core.sweep.run_fraction_sweep(..., mesh=...)`` delegate here;
 ``dist.grid.run_sweep_sharded`` / ``run_fraction_sweep_sharded`` are the
-direct API. ``dist.compat`` pins the ``shard_map``/mesh API across the JAX
-versions this repo supports (the old ``launch/`` mesh scaffold is rebased on
-it).
+direct API.
 """
-from repro.dist import compat, mesh, partition  # noqa: F401
+from repro.dist import mesh, partition  # noqa: F401
 from repro.dist.mesh import (  # noqa: F401
     auto_grid_mesh,
     client_size,

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core import tree_math as tm
-from repro.dist import compat
 from repro.dist import mesh as mesh_lib
 
 
@@ -75,8 +74,8 @@ def sharded_client_mean(mesh, stacked, weights=None):
         return jax.tree.map(
             lambda m, r: m.reshape(r.shape[1:]), total, rows)
 
-    fn = compat.shard_map(
-        body, mesh,
+    fn = jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(jax.tree.map(lambda _: P("client"), stacked),
                   P("client")),
         out_specs=jax.tree.map(lambda _: P(), stacked))
@@ -105,13 +104,16 @@ def sharded_chain_aggregate(mesh, x, g, c_i, c, *, lr: float, weights=None):
         weights = jnp.full((s,), 1.0 / s, jnp.float32)
 
     def body(g_loc, ci_loc, w_loc):
+        # zeros, not zeros_like: x is closed over from outside the
+        # shard_map and may carry a sharding of the enclosing mesh
         partial = agg_ops.chain_aggregate(
-            x, g_loc, ci_loc, jnp.zeros_like(x), weights=w_loc, lr=lr)
+            x, g_loc, ci_loc, jnp.zeros(x.shape, x.dtype), weights=w_loc,
+            lr=lr)
         delta = jax.lax.psum(partial - x, "client")  # −lr·Σ wᵢ(gᵢ−cᵢ)
         return x + delta - lr * c.astype(x.dtype)
 
-    fn = compat.shard_map(
-        body, mesh,
+    fn = jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(P("client"), P("client"), P("client")),
         out_specs=P())
     return fn(g, c_i, weights)
@@ -151,11 +153,11 @@ def sgd_round_client_sharded(mesh, problem, x, eta, key, *, k: int):
         from repro.kernels.aggregate import ops as agg_ops
 
         return agg_ops.chain_aggregate(
-            x_, g_loc, jnp.zeros_like(g_loc), jnp.zeros_like(x_),
+            x_, g_loc, jnp.zeros_like(g_loc), jnp.zeros(x_.shape, x_.dtype),
             weights=w_loc, lr=1.0)
 
-    fn = compat.shard_map(
-        body, mesh,
+    fn = jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(P("client"), P("client"), P("client")),
         out_specs=P())
     return fn(cids, keys, weights)

@@ -30,6 +30,16 @@ def _force_pallas_env() -> bool:
     return os.environ.get("REPRO_FORCE_PALLAS", "0") not in ("0", "", "false")
 
 
+def kernel_path(force_pallas: bool = False) -> str:
+    """Which path the wrappers below take: ``"mosaic"`` (compiled Pallas on
+    TPU), ``"interpret"`` (interpret-mode Pallas) or ``"ref"`` (jnp)."""
+    if _on_tpu():
+        return "mosaic"
+    if force_pallas or _force_pallas_env():
+        return "interpret"
+    return "ref"
+
+
 def chain_aggregate(x, g, c_i, c, weights=None, *, lr: float, force_pallas: bool = False):
     import jax.numpy as jnp
 

@@ -4,8 +4,10 @@
 scalar level count; one pass streams [S, BLOCK_D] tiles through VMEM
 (quantize and dequantize fused, so the int lattice never hits HBM).
 ``weighted_mean_over_clients`` is the masked-aggregate primitive: the whole
-[S] weight vector is staged per grid step next to each [S, BLOCK_D] tile
-(same layout as ``aggregate.chain_aggregate``'s weights).
+[S, 1] weight column is staged per grid step next to each [S, BLOCK_D] tile.
+Operand layout and block sizing are ``aggregate.chain_aggregate``'s: [S]
+columns, [D] rows and a levels scalar as [1, 1], so every block stays legal
+for Mosaic under ``vmap``.
 
 Both take runtime operands only — levels and weights are data, so comm
 config changes never retrace a compiled caller.
@@ -18,12 +20,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-BLOCK_D = 2048
+from repro.kernels.aggregate.aggregate import block_d_for, col, pad_cols
 
 
 def _qsgd_kernel(lv_ref, n_ref, v_ref, u_ref, o_ref):
-    lv = jnp.maximum(lv_ref[0], 1.0)
-    safe = jnp.maximum(n_ref[...].astype(jnp.float32), 1e-30)[:, None]
+    lv = jnp.maximum(lv_ref[...].astype(jnp.float32), 1.0)  # [1, 1]
+    safe = jnp.maximum(n_ref[...].astype(jnp.float32), 1e-30)  # [S, 1]
     v = v_ref[...].astype(jnp.float32)  # [S, BD]
     scaled = jnp.abs(v) / safe * lv
     lo = jnp.floor(scaled)
@@ -33,58 +35,57 @@ def _qsgd_kernel(lv_ref, n_ref, v_ref, u_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_d"))
 def qsgd_dequantize(v, u, norms, levels, *, interpret: bool = False,
-                    block_d: int = BLOCK_D):
+                    block_d: int | None = None):
     """v, u: [S, D]; norms: [S]; levels: scalar array. Returns [S, D]."""
     s, d = v.shape
-    bd = min(block_d, d)
-    pad = (-d) % bd
-    if pad:  # padded zeros quantize to zero and are sliced off below
-        v = jnp.pad(v, ((0, 0), (0, pad)))
-        u = jnp.pad(u, ((0, 0), (0, pad)))
+    bd = min(block_d or block_d_for(s, 3), d)
+    pad = (-d) % bd  # padded zeros quantize to zero and are sliced off below
+    v, u = pad_cols(v, pad), pad_cols(u, pad)
     dp = v.shape[1]
-    lv = jnp.reshape(levels, (1,)).astype(jnp.float32)
+    rows = pl.BlockSpec((s, bd), lambda j: (0, j))
 
     out = pl.pallas_call(
         _qsgd_kernel,
         grid=(dp // bd,),
         in_specs=[
-            pl.BlockSpec((1,), lambda j: (0,)),  # levels: whole scalar
-            pl.BlockSpec((s,), lambda j: (0,)),  # norms: whole vector
-            pl.BlockSpec((s, bd), lambda j: (0, j)),  # v tile
-            pl.BlockSpec((s, bd), lambda j: (0, j)),  # u tile
+            pl.BlockSpec((1, 1), lambda j: (0, 0)),  # levels: whole scalar
+            pl.BlockSpec((s, 1), lambda j: (0, 0)),  # norms: whole column
+            rows,  # v tile
+            rows,  # u tile
         ],
-        out_specs=pl.BlockSpec((s, bd), lambda j: (0, j)),
+        out_specs=rows,
         out_shape=jax.ShapeDtypeStruct((s, dp), v.dtype),
         interpret=interpret,
-    )(lv, norms, v, u)
-    return out[:, :d] if pad else out
+        name="qsgd_dequantize",
+    )(jnp.reshape(levels, (1, 1)), col(norms), v, u)
+    return out[:, :d]
 
 
 def _wmean_kernel(w_ref, t_ref, o_ref):
-    w = w_ref[...].astype(jnp.float32)
+    w = w_ref[...].astype(jnp.float32)  # [S, 1]
     t = t_ref[...].astype(jnp.float32)
-    o_ref[...] = jnp.mean(w[:, None] * t, axis=0).astype(o_ref.dtype)
+    o_ref[...] = jnp.mean(w * t, axis=0, keepdims=True).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_d"))
 def weighted_mean_over_clients(t, w, *, interpret: bool = False,
-                               block_d: int = BLOCK_D):
+                               block_d: int | None = None):
     """meanᵢ wᵢ·tᵢ over the leading axis of t: [S, D] × [S] → [D]."""
     s, d = t.shape
-    bd = min(block_d, d)
+    bd = min(block_d or block_d_for(s, 1), d)
     pad = (-d) % bd
-    if pad:
-        t = jnp.pad(t, ((0, 0), (0, pad)))
+    t = pad_cols(t, pad)
     dp = t.shape[1]
     out = pl.pallas_call(
         _wmean_kernel,
         grid=(dp // bd,),
         in_specs=[
-            pl.BlockSpec((s,), lambda j: (0,)),  # weights: whole vector
+            pl.BlockSpec((s, 1), lambda j: (0, 0)),  # weights: whole column
             pl.BlockSpec((s, bd), lambda j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bd,), lambda j: (j,)),
-        out_shape=jax.ShapeDtypeStruct((dp,), t.dtype),
+        out_specs=pl.BlockSpec((1, bd), lambda j: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((1, dp), t.dtype),
         interpret=interpret,
-    )(w, t)
-    return out[:d] if pad else out
+        name="weighted_mean_over_clients",
+    )(col(w), t)
+    return out[0, :d]

@@ -26,14 +26,9 @@ COLLECTIVE_OPS = (
 
 
 def cost_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized across JAX versions: older
-    releases return a per-device LIST of dicts (all devices run the same
-    SPMD program, so the first entry is the per-device cost), newer ones a
-    single dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
+    """``compiled.cost_analysis()`` (the per-device cost of the SPMD
+    program) as a plain dict."""
+    return dict(compiled.cost_analysis())
 
 # matches e.g.  bf16[128,7168]{1,0}  inside an HLO instruction line
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
@@ -144,13 +139,15 @@ class Roofline:
 
 
 def roofline(cost: dict, colls: Dict[str, dict], *, n_chips: int,
-             model_flops: float, links: int = 4) -> Roofline:
+             model_flops: float, device_kind: str,
+             links: int = 4) -> Roofline:
     """Derive the three roofline terms from the compiled per-device numbers.
 
     cost: compiled.cost_analysis() dict (per-device, post-partitioning).
     model_flops: 6·N·D (global); useful_ratio = model_flops / (flops·chips).
+    device_kind: the chip the terms are modelled for (``mesh.PEAKS`` key).
     """
-    hw = hardware_constants()
+    hw = hardware_constants(device_kind)
     flops = float(cost.get("flops", 0.0))
     hbm_bytes = float(cost.get("bytes accessed", 0.0))
     wire = collective_wire_bytes(colls)

@@ -24,7 +24,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.configs import INPUT_SHAPES, registry  # noqa: E402
 from repro.launch import analysis  # noqa: E402
-from repro.launch.mesh import data_shards, make_production_mesh  # noqa: E402
+from repro.launch.mesh import (  # noqa: E402
+    PRODUCTION_DEVICE_KIND, data_shards, make_production_mesh)
 from repro.models import model_zoo, transformer  # noqa: E402
 from repro.optim import sgd  # noqa: E402
 from repro.sharding import RuleSet, param_specs, use_rules  # noqa: E402
@@ -257,10 +258,12 @@ def lower_one(arch: str, shape_name: str, mesh, mesh_name: str, *,
         colls = _combine_colls(base, bumps, units)
         rec["cost_extrapolated"] = {"flops": flops, "bytes": hbytes}
         roof = analysis.roofline({"flops": flops, "bytes accessed": hbytes}, colls,
-                                 n_chips=n_chips, model_flops=mf)
+                                 n_chips=n_chips, model_flops=mf,
+                                 device_kind=PRODUCTION_DEVICE_KIND)
     else:
         roof = analysis.roofline({"flops": raw["flops"], "bytes accessed": raw["bytes"]},
-                                 raw["colls"], n_chips=n_chips, model_flops=mf)
+                                 raw["colls"], n_chips=n_chips, model_flops=mf,
+                                 device_kind=PRODUCTION_DEVICE_KIND)
         rec["note"] = "scanned-HLO cost (while-body counted once); see single-pod for exact"
     rec["roofline"] = roof.to_dict()
     return rec

@@ -34,9 +34,9 @@ from repro.sharding import RuleSet, param_specs
 
 def make_fl_mesh(clients: int = 4, data: int = 4, model: int = 16):
     """Single-pod FL mesh: the 16-way data axis split into client × data."""
-    from repro.dist import compat
+    from repro.launch.mesh import make_mesh
 
-    return compat.make_mesh(
+    return make_mesh(
         (clients, data, model), ("client", "data", "model"))
 
 

@@ -5,9 +5,7 @@
    collective formulation for *model-training* meshes without a dedicated
    client axis; ``repro.dist.client_axis`` is the maintained client-axis
    layer (per-shard Pallas aggregation + one psum join) and
-   ``repro.dist.grid`` is the production path for experiment grids. The
-   ``shard_map`` calls go through ``repro.dist.compat`` (one home for the
-   JAX version skew).
+   ``repro.dist.grid`` is the production path for experiment grids.
 
 The pjit path (`launch.fedchain`) gives each client group its own parameter
 replica along a mesh axis. This module is the alternative single-pod
@@ -32,7 +30,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core import tree_math as tm
-from repro.dist import compat
 
 
 def client_groups(data_size: int, clients: int):
@@ -83,9 +80,10 @@ def make_grouped_local_steps(
         params, losses = jax.lax.scan(body, params, batches)
         return params, jnp.mean(losses)
 
-    return compat.shard_map(
+    return jax.shard_map(
         local_steps,
-        mesh,
+        mesh=mesh,
+        check_vma=False,
         in_specs=(P(), P(None, "data")),
         out_specs=(P(), P()),
     )
@@ -99,7 +97,8 @@ def make_grouped_sync(*, mesh, clients: int):
         return jax.tree.map(
             lambda p: jax.lax.pmean(p, axis_name="data"), params)
 
-    return compat.shard_map(sync, mesh, in_specs=(P(),), out_specs=P())
+    return jax.shard_map(sync, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                         check_vma=False)
 
 
 def run_grouped_fedavg_round(

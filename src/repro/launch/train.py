@@ -184,6 +184,9 @@ def run_fedchain(cfg, args):
 
 def main(argv=None):
     args = parse_args(argv)
+    from repro import compile_cache
+
+    compile_cache.enable()
     cfg = registry.get_config(args.arch, smoke=args.smoke)
     if args.smoke:
         cfg = dataclasses.replace(cfg, max_seq_len=max(args.seq * 2, 256))

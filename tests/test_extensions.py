@@ -55,9 +55,9 @@ def test_metrics_logger_roundtrip(tmp_path):
 def test_fsdp_spec_shards_big_weights():
     from repro.sharding import RuleSet, param_specs
 
-    from repro.dist import compat
+    from jax.sharding import AbstractMesh
 
-    mesh = compat.abstract_mesh((2, 2), ("data", "model"))
+    mesh = AbstractMesh((2, 2), ("data", "model"))
     rs = RuleSet(mesh, fsdp=True)
     shapes = {
         "seg0": {"mlp": {"w_in": jax.ShapeDtypeStruct((2, 1024, 1024), jnp.float32)}},
@@ -72,9 +72,9 @@ def test_fsdp_spec_shards_big_weights():
 def test_attn_fallback_spec():
     from repro.sharding import RuleSet, param_specs
 
-    from repro.dist import compat
+    from jax.sharding import AbstractMesh
 
-    mesh = compat.abstract_mesh((1, 4), ("data", "model"))
+    mesh = AbstractMesh((1, 4), ("data", "model"))
     shapes = {"attn": {"wq": jax.ShapeDtypeStruct((64, 6, 16), jnp.float32)}}
     # 6 heads % 4 != 0: default replicates, fallback shards embed(64)
     plain = param_specs(shapes, RuleSet(mesh))["attn"]["wq"]

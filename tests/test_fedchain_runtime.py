@@ -101,10 +101,10 @@ def test_shardmap_grouped_fedavg_matches_reference():
         import json
         import jax, jax.numpy as jnp
         import numpy as np
-        from repro.dist import compat
+        from repro.launch.mesh import make_mesh
         from repro.launch.fedchain_shardmap import run_grouped_fedavg_round, client_groups
 
-        mesh = compat.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         # toy quadratic "model": params [d]; loss per batch row ||x - p||^2
         def loss_fn(p, batch):
             return jnp.mean(jnp.sum((batch - p[None, :]) ** 2, -1))

@@ -172,3 +172,30 @@ def test_fused_round_end_to_end_matches_ref_path(monkeypatch):
         np.asarray(ref.state.comm.residual), rtol=1e-4, atol=1e-6)
     np.testing.assert_array_equal(np.asarray(fused.state.comm.bits_up),
                                   np.asarray(ref.state.comm.bits_up))
+
+
+def test_fused_fedavg_round_with_lossy_downlink_matches_ref_path(monkeypatch):
+    """Under a lossy downlink FedAvg's clients start at the downlink
+    reconstruction x_start ≠ x; the fused EF round must fold the
+    reconstruct-then-lerp offset lr·(x_start − x) in exactly as the unfused
+    ref path does, so both histories agree to float tolerance."""
+    from repro.comm import CommPlan, Leg
+    from repro.data import problems
+
+    p = problems.quadratic_problem(
+        jax.random.PRNGKey(0), num_clients=8, dim=16, mu=0.1, beta=1.0,
+        zeta=1.0, sigma=0.2, sigma_f=0.05)
+    plan = CommPlan(uplink=Leg("topk", spars_k=2, error_feedback=True),
+                    downlink=Leg("qsgd", qsgd_bits=4), participation=0.5)
+    algo = A.FedAvg(eta=0.2, local_steps=2, inner_batch=2)
+    run = lambda: runner.run(  # noqa: E731
+        algo, p, p.init_params(jax.random.PRNGKey(0)), 6,
+        jax.random.PRNGKey(0), comm=plan)
+    monkeypatch.delenv("REPRO_FORCE_PALLAS", raising=False)
+    ref = run()
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
+    fused = run()
+    np.testing.assert_allclose(np.asarray(fused.history),
+                               np.asarray(ref.history), rtol=1e-4, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(fused.x_hat), np.asarray(ref.x_hat),
+                               rtol=1e-4, atol=1e-6)

@@ -30,10 +30,10 @@ def test_cache_rules():
 
 
 def test_ruleset_divisibility_and_dedup():
-    from repro.dist import compat
+    from repro.launch.mesh import make_mesh
     from repro.sharding import RuleSet
 
-    mesh = compat.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     rs = RuleSet(mesh)
     # axis size 1 always divides
     spec = rs.spec_for(("experts", "embed", "ff"), (4, 8, 16))
@@ -123,6 +123,44 @@ def test_shuffled_heterogeneity_partition():
     cx, cy = shuffled_heterogeneity(feats, homogeneous_frac=0.0, num_clients=5)
     assert set(np.unique(cy[0])) == {0, 1}
     assert set(np.unique(cy[4])) == {8, 9}
+
+
+def test_pathological_shards_partition():
+    from repro.data.partition import pathological_shards
+
+    n = 1200  # 10 classes × 120 → 20 shards of 60, two per client
+    labels = np.repeat(np.arange(10), 120)
+    feats = np.arange(n, dtype=np.float32)[:, None] * np.ones((1, 3))
+    cx, cy = pathological_shards(feats, labels, num_clients=10, seed=3)
+    assert cx.shape == (10, 120, 3) and cy.shape == (10, 120)
+    # every sample dealt exactly once, features travel with their labels
+    np.testing.assert_array_equal(np.sort(cx[..., 0].ravel()), np.arange(n))
+    np.testing.assert_array_equal(labels[cx[..., 0].astype(int)], cy)
+    # each client holds two label-sorted shards: at most two classes
+    assert max(len(np.unique(c)) for c in cy) <= 2
+
+
+def test_compile_cache_dir(monkeypatch):
+    from repro import compile_cache
+
+    seen = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: seen.append((name, value)))
+    monkeypatch.setenv(compile_cache.ENV, "/somewhere/else")
+    assert compile_cache.enable() == "/somewhere/else"
+    assert seen == []  # JAX reads the variable itself
+    monkeypatch.delenv(compile_cache.ENV)
+    assert compile_cache.enable() == compile_cache.DEFAULT_DIR
+    assert seen == [("jax_compilation_cache_dir", compile_cache.DEFAULT_DIR)]
+    assert compile_cache.DEFAULT_DIR.endswith(os.sep + ".jax_cache")
+
+
+def test_hardware_constants_keyed_by_device_kind():
+    from repro.launch.mesh import hardware_constants
+
+    assert hardware_constants("TPU v5 lite")["hbm_bw"] == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        hardware_constants("cpu")
 
 
 def test_token_stream_deterministic():
