@@ -1,0 +1,464 @@
+"""Plain reference of one grid cell: chained FedAvg -> SGD with compressed
+links, partial participation and, where the traffic asks, UCB selection.
+
+It is written from the algorithms' descriptions, in straightforward
+``jax.numpy``, one cell at a time, and imports nothing of the program under
+test. What makes it comparable with the program cell for cell is that it
+draws the same random numbers: a cell's randomness is a function of its
+seed, and the derivation below is the one the engine documents.
+
+Per cell (seed s, stepsize multiplier m, mask seed, selection seed, fold f):
+
+* Keys. key = PRNGKey(s); four stage keys = split(key, 4). Stage 1 (FedAvg)
+  takes its B1 = round(R/2) round keys from split(stage_key[0], B1), stage 2
+  (SGD) its B2 = R - B1 - 1 from split(stage_key[2], B2). The Lemma H.2
+  selection between them uses stage_key[1]. The scan runs R rows: B1 FedAvg
+  rounds, one selection row, B2 SGD rounds.
+* Participation. With a mask schedule: mk = fold_in(PRNGKey(mask_seed), f);
+  row r draws u = uniform(split(mk, R)[r], (N,)) and keeps the S clients of
+  smallest u. With UCB: the same keys from the selection seed drive the
+  policy (below).
+* A round key k yields k_sample, k_work = split(k); the comm stream is
+  c = fold_in(k, 0x636D) and the downlink stream fold_in(c, 2).
+* Client order. Every round enumerates all N clients in a Fisher-Yates
+  order: for i < N, j_i = randint(split(k_sample, N)[i], i, N), swap i, j_i.
+* QSGD with b bits on rows v [S, d] and key k: u = uniform(k, (S, d)),
+  L = 2^b - 1, ||v|| per row, q = floor(|v|/||v|| L) + [u < frac], out =
+  sign(v) ||v|| q / L. A parameter pytree compresses leaf by leaf in
+  flattening order, leaf i with split(k, n_leaves)[i] (one leaf: k itself).
+* Downlink (server error feedback): delta = x - ref + res, c = C(delta),
+  clients hold ref + c, the server keeps res = delta - c, ref = ref + c.
+* FedAvg round: clients start from the downlink reconstruction x_s; client
+  i (key split(k_work, N)[i]) takes local steps, each the mean of
+  ``inner_batch`` minibatch gradients (step keys split(key_i, steps), query
+  keys split(step key, inner_batch)); it uplinks y_i - x_s. With uplink
+  error feedback: d_i = y_i - x_s + e_i, c_i = C(d_i), x = x_s + sum over
+  participants c_i / S, participants keep e_i = d_i - c_i. Without: x =
+  mean over participants of (x_s + c_i).
+* SGD round: gradients at the downlink reconstruction, K queries a client
+  (keys split(k_work, N K) in client-major order), uplinked as g_i (+ e_i
+  with feedback); x = x - eta * mean over participants of C(.). The output
+  is the (1 - eta mu)^-r weighted average of the iterates, or the last.
+* A minibatch query: idx = randint(key, (B,), 0, n); the loss of the
+  client's rows idx; gradient by autodiff.
+* Selection row: candidates x0 and the FedAvg iterate; k_sample, k_vals =
+  split(stage_key[1]); every client (Fisher-Yates order of k_sample) scores
+  both on the same K queries (keys split(k_vals, N K)); keep x0 if its mean
+  value is not larger. SGD starts from the winner; feedback residuals are
+  cleared there.
+* UCB (per row, before the round, on the active stage's iterate x): probe
+  every client once, v_i = value of a minibatch query with key
+  split(fold_in(sel_key, 0x736C), N)[i]; reward of last round's participants
+  = last probe - v; running mean over their counts; score = mean +
+  c sqrt(log(t + 1) / max(count, 1)), never-chosen clients first; the S
+  highest scores participate (ties to the lower index).
+* History: the global loss (mean over clients of the client's full-data
+  loss) of the active stage's output after each row; the selection row
+  records the winner's. Bits follow the closed forms: uplink
+  S_r (32 + d (b + 1)) per QSGD leaf (32 d uncompressed), the same for the
+  downlink, 2 * 32 N up and 2 * 32 D N down on the selection row, and
+  32 N up for each UCB probe.
+
+``dtype`` sets the precision of every array and ``precision`` that of the
+matrix products: float32 at ``highest`` for the reference; the control
+drops one step (``high``, three bf16 passes), and bfloat16 arrays are a
+further reading.
+"""
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+COMM_TAG = 0x636D
+DOWN_TAG = 2
+PROBE_TAG = 0x736C
+
+
+def leaves(tree):
+    return jax.tree.leaves(tree)
+
+
+def _tree_map(f, *trees):
+    return jax.tree.map(f, *trees)
+
+
+@dataclasses.dataclass
+class CellResult:
+    history: np.ndarray  # [R]
+    x_hat: object
+    bits_up: np.ndarray  # [R]
+    bits_down: np.ndarray  # [R]
+    kept: bool
+    masks: np.ndarray  # [R, N]
+    margins: np.ndarray  # [R] UCB: relative gap of the S-th score to the next
+
+
+class Reference:
+    """Runs cells of one configuration under one traffic mix."""
+
+    def __init__(self, model, config: dict, traffic: dict, features,
+                 classes, *, dtype=jnp.float32, precision: str = "highest",
+                 fault: str | None = None):
+        self.model = model
+        self.cfg = config
+        self.traffic = traffic
+        self.dtype = jnp.dtype(dtype)
+        self.precision = precision
+        self.fault = fault
+        self.set_population(features, classes)
+        self.n_clients, self.n_per = features.shape[:2]
+        self.batch = model.batch(config)
+        self.l2 = float(config["l2"])
+        m = config["method"]
+        self.local, self.glob = m["local"], m["global"]
+        self.sel_k = int(m["selection_k"])
+        self.rounds = int(traffic["rounds"])
+        self.b1 = max(1, int(round(m["local_fraction"] * self.rounds)))
+        self.b2 = max(1, self.rounds - self.b1 - 1)
+        self.s_part = max(1, int(round(traffic["participation"]
+                                       * self.n_clients)))
+        self.up, self.down = traffic["uplink"], traffic["downlink"]
+        self.policy = traffic.get("policy")
+        self._jit()
+
+    def set_population(self, features, classes):
+        """The data every jitted step takes as an argument (never as a
+        constant, so a new population of the same shape compiles nothing)."""
+        X = features.astype(self.dtype)
+        Y = self.model.labels(classes)
+        if Y.dtype != jnp.int32:
+            Y = Y.astype(self.dtype)
+        self.data = (X, Y)
+
+    # -- building blocks -------------------------------------------------
+
+    def _loss(self, x, X, y):
+        return self.model.loss(x, X, y, self.l2)
+
+    def _query(self, data, cid, key):
+        idx = jax.random.randint(key, (self.batch,), 0, self.n_per)
+        return data[0][cid][idx], data[1][cid][idx]
+
+    def _grad(self, data, x, cid, key):
+        X, y = self._query(data, cid, key)
+        return jax.grad(self._loss)(x, X, y)
+
+    def _value(self, data, x, cid, key):
+        X, y = self._query(data, cid, key)
+        return self._loss(x, X, y)
+
+    def _global_loss(self, data, x):
+        per = jax.vmap(lambda X, y: self._loss(x, X, y))(*data)
+        return jnp.mean(per)
+
+    def _order(self, key):
+        """Fisher-Yates over all N clients."""
+        n = self.n_clients
+        keys = jax.random.split(key, n)
+        js = jax.vmap(lambda k, i: jax.random.randint(k, (), i, n))(
+            keys, jnp.arange(n, dtype=jnp.int32))
+
+        def swap(i, idx):
+            a, b = idx[i], idx[js[i]]
+            return idx.at[i].set(b).at[js[i]].set(a)
+
+        return jax.lax.fori_loop(0, n, swap, jnp.arange(n, dtype=jnp.int32))
+
+    def _qsgd_rows(self, v, key, bits):
+        u = jax.random.uniform(key, v.shape, jnp.float32).astype(v.dtype)
+        norm = jnp.sqrt(jnp.sum(v * v, axis=1, keepdims=True))
+        norm = jnp.maximum(norm, jnp.asarray(1e-30, v.dtype))
+        levels = jnp.asarray(2.0 ** bits - 1.0, v.dtype)
+        scaled = jnp.abs(v) / norm * levels
+        lo = jnp.floor(scaled)
+        q = lo + (u < scaled - lo).astype(v.dtype)
+        return jnp.sign(v) * norm * (q / levels)
+
+    def _compress(self, tree, key, leg):
+        """Leaf-wise compression of per-client rows (leaves [S, ...])."""
+        if leg["compressor"] == "identity":
+            return tree
+        if leg["compressor"] != "qsgd":
+            raise ValueError(f"reference knows identity and qsgd, not "
+                             f"{leg['compressor']!r}")
+        flat, treedef = jax.tree.flatten(tree)
+        keys = [key] if len(flat) == 1 else list(
+            jax.random.split(key, len(flat)))
+        out = [self._qsgd_rows(l.reshape(l.shape[0], -1), k,
+                               leg["qsgd_bits"]).reshape(l.shape)
+               for l, k in zip(flat, keys)]
+        return jax.tree.unflatten(treedef, out)
+
+    def _downlink(self, x, ref, res, key):
+        if self.down["compressor"] == "identity":
+            return x, x, _tree_map(jnp.zeros_like, x)
+        delta = _tree_map(lambda a, r, e: a - r + e, x, ref, res)
+        c = self._compress(_tree_map(lambda l: l[None], delta), key,
+                           self.down)
+        c = _tree_map(lambda l: l[0], c)
+        recon = _tree_map(jnp.add, ref, c)
+        return recon, recon, _tree_map(jnp.subtract, delta, c)
+
+    def _aggregate(self, x_base, rows, mask_rows, eta):
+        """x_base - eta * mean over participants of rows (leaves [N, ...])."""
+        m = mask_rows.astype(self.dtype)
+        total = jnp.maximum(jnp.sum(m), 1.0)
+        if self.fault == "half_clients":
+            # planted fault: only the first half of the rows enter the mean
+            half = self.n_clients // 2
+            m = m * (jnp.arange(self.n_clients) < half).astype(self.dtype)
+            total = jnp.maximum(jnp.sum(m), 1.0)
+
+        def leaf(xb, r):
+            w = m.reshape((-1,) + (1,) * (r.ndim - 1))
+            return xb - eta * jnp.sum(w * r, axis=0) / total
+
+        return _tree_map(leaf, x_base, rows)
+
+    # -- rounds ----------------------------------------------------------
+
+    def _fedavg_round(self, data, x, ref, dres, ures, key, mask, eta):
+        k_sample, k_local = jax.random.split(key)
+        order = self._order(k_sample)
+        ckey = jax.random.fold_in(key, COMM_TAG)
+        x_s, ref, dres = self._downlink(x, ref, dres,
+                                        jax.random.fold_in(ckey, DOWN_TAG))
+        steps, inner = self.local["local_steps"], self.local["inner_batch"]
+
+        def local(cid, k):
+            def step(y, ks):
+                qs = jax.random.split(ks, inner)
+                gs = jax.vmap(lambda q: self._grad(data, y, cid, q))(qs)
+                g = _tree_map(lambda a: jnp.mean(a, axis=0), gs)
+                return _tree_map(lambda a, b: a - eta * b, y, g), None
+
+            y, _ = jax.lax.scan(step, x_s, jax.random.split(k, steps))
+            return y
+
+        y = jax.vmap(local)(order, jax.random.split(k_local, self.n_clients))
+        delta = _tree_map(lambda a, b: a - b, y, x_s)
+        m = mask[order]
+        ef = self.up.get("error_feedback", False)
+        if ef:
+            e = _tree_map(lambda t: t[order], ures)
+            delta = _tree_map(jnp.add, delta, e)
+        c = self._compress(delta, ckey, self.up)
+        x_new = self._aggregate(x_s, c, m, -1.0)
+        if ef:
+            keep = _tree_map(
+                lambda d, cc, ee: jnp.where(
+                    m.reshape((-1,) + (1,) * (d.ndim - 1)) > 0, d - cc, ee),
+                delta, c, e)
+            ures = _tree_map(lambda t, v: t.at[order].set(v), ures, keep)
+        if self.fault == "frozen":
+            x_new = x
+        return x_new, ref, dres, ures
+
+    def _sgd_round(self, data, x, avg, wprime, ref, dres, ures, key, mask,
+                   eta):
+        k_sample, k_grad = jax.random.split(key)
+        order = self._order(k_sample)
+        ckey = jax.random.fold_in(key, COMM_TAG)
+        x_b, ref, dres = self._downlink(x, ref, dres,
+                                        jax.random.fold_in(ckey, DOWN_TAG))
+        k = self.glob["k"]
+        qkeys = jax.random.split(k_grad, self.n_clients * k).reshape(
+            self.n_clients, k, -1)
+
+        def client(cid, ks):
+            gs = jax.vmap(lambda q: self._grad(data, x_b, cid, q))(ks)
+            return _tree_map(lambda a: jnp.mean(a, axis=0), gs)
+
+        g = jax.vmap(client)(order, qkeys)
+        m = mask[order]
+        ef = self.up.get("error_feedback", False)
+        if ef:
+            e = _tree_map(lambda t: t[order], ures)
+            g = _tree_map(jnp.add, g, e)
+        c = self._compress(g, ckey, self.up)
+        x_new = self._aggregate(x, c, m, eta)
+        if ef:
+            keep = _tree_map(
+                lambda d, cc, ee: jnp.where(
+                    m.reshape((-1,) + (1,) * (d.ndim - 1)) > 0, d - cc, ee),
+                g, c, e)
+            ures = _tree_map(lambda t, v: t.at[order].set(v), ures, keep)
+        if self.fault == "frozen":
+            x_new = x
+        decay = jnp.clip(1.0 - eta * self.glob["mu_avg"], 0.0, 1.0)
+        wprime = 1.0 + decay * wprime
+        avg = _tree_map(lambda a, b: a + (b - a) / wprime, avg, x_new)
+        return x_new, avg, wprime, ref, dres, ures
+
+    def _select(self, data, anchor, cand, key):
+        k_sample, k_vals = jax.random.split(key)
+        order = self._order(k_sample)
+        n, k = self.n_clients, self.sel_k
+        qkeys = jax.random.split(k_vals, n * k).reshape(n, k, -1)
+
+        def value(x):
+            per = jax.vmap(lambda cid, ks: jnp.mean(jax.vmap(
+                lambda q: self._value(data, x, cid, q))(ks)))(order, qkeys)
+            return jnp.mean(per)
+
+        keep = value(anchor) <= value(cand)
+        best = _tree_map(lambda a, b: jnp.where(keep, a, b), anchor, cand)
+        return best, keep
+
+    def _ucb(self, data, x, st, key):
+        counts, values, last_probe, last_mask, t = st
+        n = self.n_clients
+        pkeys = jax.random.split(jax.random.fold_in(key, PROBE_TAG), n)
+        v = jax.vmap(lambda i, kk: self._value(data, x, i, kk))(
+            jnp.arange(n, dtype=jnp.int32), pkeys).astype(jnp.float32)
+        reward = last_probe - v
+        cnt = jnp.maximum(counts, 1.0)
+        values = jnp.where(last_mask > 0,
+                           values + (reward - values) / cnt, values)
+        t = t + 1.0
+        bonus = self.policy.get("ucb_c", 1.0) * jnp.sqrt(jnp.log(t + 1.0)
+                                                         / cnt)
+        score = jnp.where(counts < 0.5, jnp.inf, values + bonus)
+        ranks = jnp.argsort(jnp.argsort(-score, stable=True), stable=True)
+        mask = (ranks < self.s_part).astype(jnp.float32)
+        # how far the S-th score lies above the next: a choice that rounding
+        # can flip has a margin near zero (ties among the untried: +inf)
+        top = -jnp.sort(-score)
+        edge, nxt = top[self.s_part - 1], top[self.s_part]
+        margin = jnp.where(jnp.isinf(edge), jnp.inf,
+                           (edge - nxt) / jnp.maximum(jnp.abs(edge), 1e-30))
+        return mask, (counts + mask, values, v, mask, t), margin
+
+    def _jit(self):
+        self.j_fedavg = jax.jit(self._fedavg_round)
+        self.j_sgd = jax.jit(self._sgd_round)
+        self.j_select = jax.jit(self._select)
+        self.j_loss = jax.jit(self._global_loss)
+        self.j_ucb = jax.jit(self._ucb)
+
+    # -- bits ------------------------------------------------------------
+
+    def _leg_bits(self, leg, dims):
+        """Per-client bits of one pytree on ``leg``, in float32 as billed."""
+        total = 0
+        for d in dims:
+            if leg["compressor"] == "identity":
+                total = total + np.float32(32.0 * d)
+            else:
+                total = total + (np.float32(32.0) + np.float32(d)
+                                 * (np.float32(leg["qsgd_bits"])
+                                    + np.float32(1.0)))
+        return np.float32(total)
+
+    # -- one cell --------------------------------------------------------
+
+    def masks(self, mask_seed: int, fold: int):
+        n_rows = self.b1 + 1 + self.b2
+        if self.traffic["participation"] >= 1.0:
+            return np.ones((n_rows, self.n_clients), np.float32)
+        mk = jax.random.fold_in(jax.random.PRNGKey(mask_seed), fold)
+        u = np.asarray(jax.vmap(
+            lambda k: jax.random.uniform(k, (self.n_clients,)))(
+                jax.random.split(mk, n_rows)))
+        ranks = np.argsort(np.argsort(u, axis=1, kind="stable"), axis=1,
+                           kind="stable")
+        return (ranks < self.s_part).astype(np.float32)
+
+    def run_cell(self, x0, *, seed: int, mult: float, mask_seed: int,
+                 sel_seed: int, fold: int, rows: int | None = None
+                 ) -> CellResult:
+        """Replay one cell; ``rows`` stops after that many schedule rows."""
+        with jax.default_matmul_precision(self.precision):
+            return self._run_cell(x0, seed, mult, mask_seed, sel_seed, fold,
+                                  rows)
+
+    def _run_cell(self, x0, seed, mult, mask_seed, sel_seed, fold, rows):
+        n_rows = self.b1 + 1 + self.b2
+        rows = n_rows if rows is None else rows
+        x0 = _tree_map(lambda l: l.astype(self.dtype), x0)
+        dims = [int(np.prod(l.shape)) for l in leaves(x0)]
+        key = jax.random.PRNGKey(seed)
+        stage = jax.random.split(key, 4)
+        rk1 = jax.random.split(stage[0], self.b1)
+        rk2 = jax.random.split(stage[2], self.b2)
+        # stepsizes as the engine forms them: float32 base times multiplier
+        eta1 = jnp.asarray(np.float32(self.local["eta"]) * np.float32(mult),
+                           self.dtype)
+        eta2 = jnp.asarray(np.float32(self.glob["eta"]) * np.float32(mult),
+                           self.dtype)
+        ef = self.up.get("error_feedback", False)
+        ures = (_tree_map(lambda l: jnp.zeros((self.n_clients,) + l.shape,
+                                              self.dtype), x0)
+                if ef else None)
+        ref = _tree_map(jnp.zeros_like, x0)
+        dres = _tree_map(jnp.zeros_like, x0)
+        sched = None if self.policy else self.masks(mask_seed, fold)
+        if self.policy:
+            sel_keys = jax.random.split(
+                jax.random.fold_in(jax.random.PRNGKey(sel_seed), fold),
+                n_rows)
+            z = jnp.zeros((self.n_clients,), jnp.float32)
+            pst = (z, z, z, z, jnp.zeros((), jnp.float32))
+        up_bits = self._leg_bits(self.up, dims)
+        down_bits = self._leg_bits(self.down, dims)
+        probe_bits = np.float32(32.0 * self.n_clients) if self.policy else \
+            np.float32(0.0)
+        x = x0
+        anchor = x0
+        avg = wprime = None
+        kept = False
+        hist, bu, bd, masks, margins = [], [], [], [], []
+        for r in range(rows):
+            in_stage1 = r < self.b1
+            sel_row = r == self.b1
+            if r == self.b1 + 1:  # hand-off into SGD from the anchor
+                x = anchor
+                avg, wprime = anchor, jnp.asarray(1.0, self.dtype)
+                if ef:
+                    ures = _tree_map(jnp.zeros_like, ures)
+                dres = _tree_map(jnp.zeros_like, dres)
+            if self.policy:
+                mask, pst, margin = self.j_ucb(self.data, x, pst, sel_keys[r])
+                mask = np.asarray(mask)
+                margins.append(float(margin))
+            else:
+                mask = sched[r]
+            masks.append(mask)
+            s_r = np.float32(np.sum(mask, dtype=np.float32))
+            if sel_row:
+                anchor, keep = self.j_select(self.data, x0, x, stage[1])
+                kept = bool(keep)
+                hist.append(float(self.j_loss(self.data, anchor)))
+                bu.append(np.float32(2.0 * 32.0 * self.n_clients)
+                          + probe_bits)
+                bd.append(np.float32(2.0 * 32.0 * sum(dims)
+                                     * self.n_clients))
+                continue
+            if in_stage1:
+                x, ref, dres, ures = self.j_fedavg(
+                    self.data, x, ref, dres, ures, rk1[r], jnp.asarray(mask), eta1)
+                out = x
+            else:
+                x, avg, wprime, ref, dres, ures = self.j_sgd(
+                    self.data, x, avg, wprime, ref, dres, ures, rk2[r - self.b1 - 1],
+                    jnp.asarray(mask), eta2)
+                out = x if self.glob["output_mode"] == "last" else avg
+            hist.append(float(self.j_loss(self.data, out)))
+            bu.append(np.float32(s_r * up_bits) + probe_bits)
+            bd.append(np.float32(s_r * down_bits))
+        if rows > self.b1 + 1:
+            x_hat = x if self.glob["output_mode"] == "last" else avg
+        else:
+            x_hat = x
+        return CellResult(
+            history=np.asarray(hist, np.float64),
+            x_hat=_tree_map(lambda l: np.asarray(l.astype(jnp.float32)),
+                            x_hat),
+            bits_up=np.asarray(bu, np.float32),
+            bits_down=np.asarray(bd, np.float32),
+            kept=kept, masks=np.asarray(masks, np.float32),
+            margins=np.asarray(margins, np.float64))
+
