@@ -4,11 +4,10 @@ A *traced scope* is a function whose Python body executes under a JAX trace
 — anything passed (by name or as a lambda) to ``jax.jit`` / ``lax.scan`` /
 ``jax.vmap`` / ``shard_map`` / ``pallas_call`` / control-flow combinators,
 anything decorated with ``jit``, anything that bumps a ``TRACE_WHITELIST``
-counter (``TRACE_COUNTS``, the repo's trace-time marker, and
-``TRACE_EVENTS``, the obs event sink mirrored beside it), and anything
-lexically nested inside one of those. The detection over-approximates (a name collision marks an unrelated
-same-named def) — acceptable for a lint whose false positives are one
-``# repro: allow[Rn]`` away.
+counter (``TRACE_COUNTS``, the repo's trace-time marker), and anything
+lexically nested inside one of those. The detection over-approximates (a
+name collision marks an unrelated same-named def) — acceptable for a lint
+whose false positives are one ``# repro: allow[Rn]`` away.
 
 Suppressions: ``# repro: allow[R1]`` (or ``allow[R1,R4]``) on the violating
 line or on the line directly above it. Every suppression is inventoried in
@@ -114,11 +113,10 @@ def local_bindings(fn_node) -> Set[str]:
     return out
 
 
-# the ONLY module-level objects a traced body may mutate: the trace-time
-# bookkeeping counters. TRACE_COUNTS is the retrace-discipline marker
-# (repro.core.runner); TRACE_EVENTS is the obs event sink bumped beside it
-# (repro.obs.events) — both record "this body traced", never per-call state.
-TRACE_WHITELIST = {"TRACE_COUNTS", "TRACE_EVENTS"}
+# the ONLY module-level object a traced body may mutate: the trace-time
+# bookkeeping counter TRACE_COUNTS, the retrace-discipline marker
+# (repro.core.runner) — it records "this body traced", never per-call state.
+TRACE_WHITELIST = {"TRACE_COUNTS"}
 
 
 def _is_trace_counts_target(node) -> bool:

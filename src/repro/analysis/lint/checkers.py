@@ -81,8 +81,7 @@ class ClosureArrayChecker(Checker):
 class SideEffectChecker(Checker):
     """R2: no Python side effects in traced bodies — they run once per
     trace, not once per call, so anything but a ``base.TRACE_WHITELIST``
-    counter bump (``TRACE_COUNTS``, ``TRACE_EVENTS``) is a silent
-    correctness bug."""
+    counter bump (``TRACE_COUNTS``) is a silent correctness bug."""
 
     rule = "R2"
     title = "no Python side effects in traced bodies except TRACE_WHITELIST"

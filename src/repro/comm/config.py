@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from repro.comm import compressors
 from repro.comm.compressors import COMP_IDENTITY, COMP_QSGD, CommParams
 from repro.core import tree_math as tm
+from repro.obs.profile import scope
 
 # fold_in tag deriving the comm PRNG stream from a round key WITHOUT
 # disturbing the key splits the algorithms already perform (bit-exactness of
@@ -210,6 +211,7 @@ def account_round(comm: CommState, dims, *, up_vectors: int = 0,
                          bits_down=comm.bits_down + down)
 
 
+@scope("uplink")
 def uplink(comm: CommState, payload, cids, key, *, ref=None,
            use_ef: bool = True, leg: str = "up"):
     """Compress one batch of per-client uplink pytrees.
@@ -261,6 +263,7 @@ def uplink(comm: CommState, payload, cids, key, *, ref=None,
     return out, comm
 
 
+@scope("downlink")
 def downlink(comm: CommState, payload, key):
     """Compress the round's server→client broadcast with bidirectional
     error feedback.
@@ -293,6 +296,7 @@ def downlink(comm: CommState, payload, key):
     return recon, comm._replace(down_ref=recon, down_residual=new_res)
 
 
+@scope("downlink")
 def downlink_second(comm: CommState, payload, key):
     """Compress a round's SECOND broadcast (SCAFFOLD's server variate c,
     SSNM's snapshot point) on the downlink leg — stateless: no reference,
@@ -308,6 +312,7 @@ def downlink_second(comm: CommState, payload, key):
         payload, comp)
 
 
+@scope("uplink")
 def uplink_fused_apply(comm: CommState, payload, cids, key, x, eta, *,
                        ref=None, force_pallas: bool = False):
     """One fused uplink + error-feedback + server-apply round.

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import tree_math as tm
+from repro.obs.profile import scope
 
 
 REQUIRED_STATE_FIELDS = ("x", "eta", "r")
@@ -74,6 +75,7 @@ def weighted_client_mean(stacked, weight_scale):
     return jax.tree.map(lambda m, t: m.reshape(t.shape[1:]), means, stacked)
 
 
+@scope("server")
 def client_mean(x, stacked, weight_scale=None):
     """Mean over the leading client axis of ``stacked``, routed through the
     Pallas ``mean_over_clients`` kernel when params are flat vectors (``x`` is
@@ -93,6 +95,7 @@ def client_mean(x, stacked, weight_scale=None):
     return tm.tree_mean_leading(stacked)
 
 
+@scope("server")
 def fused_server_step(x, g_per, eta, *, c_i=None, c_mean=None,
                       weight_scale=None):
     """The (variance-reduced) server update x − η·(meanᵢ(gᵢ − cᵢ) + c̄).
@@ -131,6 +134,7 @@ def fused_server_step(x, g_per, eta, *, c_i=None, c_mean=None,
     return tm.tree_axpy(-eta, g, x)
 
 
+@scope("sample")
 def sample_clients(key, num_clients: int, s: int):
     """S of N uniformly without replacement (paper §2).
 
@@ -163,6 +167,7 @@ def sample_clients(key, num_clients: int, s: int):
     return idx[:s]
 
 
+@scope("local")
 def grad_k(problem, x, client_ids, key, k: int, *, keys=None):
     """Algo 7 ``Grad``: per-client average of K stochastic gradients at x.
 

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from repro.core import tree_math as tm
 from repro.core.algorithms import base
+from repro.obs.profile import scope
 
 
 class FedAvgState(NamedTuple):
@@ -79,9 +80,10 @@ class FedAvg(base.FederatedAlgorithm):
             # is the delta wire reference
             x_start, comm = comm_lib.downlink(
                 comm, state.x, comm_lib.downlink_key(key))
-        y_final = jax.vmap(
-            lambda cid, kk: self._local(problem, x_start, cid, kk, state.eta)
-        )(cids, keys)
+        with scope("local"):
+            y_final = jax.vmap(
+                lambda cid, kk: self._local(problem, x_start, cid, kk,
+                                            state.eta))(cids, keys)
         if comm is not None:
             from repro.kernels.aggregate import ops as agg_ops
 
@@ -100,14 +102,17 @@ class FedAvg(base.FederatedAlgorithm):
             else:
                 y_hat, comm = comm_lib.uplink(
                     comm, y_final, cids, comm_lib.comm_key(key), ref=x_start)
-                scale = comm_lib.participation_scale(comm.mask, cids)
-                y_mean = base.client_mean(state.x, y_hat, weight_scale=scale)
-                x = tm.tree_lerp(self.server_lr, state.x, y_mean)
+                with scope("server"):
+                    scale = comm_lib.participation_scale(comm.mask, cids)
+                    y_mean = base.client_mean(state.x, y_hat,
+                                              weight_scale=scale)
+                    x = tm.tree_lerp(self.server_lr, state.x, y_mean)
             comm = comm_lib.account_round(
                 comm, state.x, up_vectors=1, down_vectors=1)
         else:
-            y_mean = base.client_mean(state.x, y_final)
-            x = tm.tree_lerp(self.server_lr, state.x, y_mean)
+            with scope("server"):
+                y_mean = base.client_mean(state.x, y_final)
+                x = tm.tree_lerp(self.server_lr, state.x, y_mean)
         return FedAvgState(x=x, eta=state.eta, r=state.r + 1, comm=comm)
 
     def init(self, problem, x0):

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.core import tree_math as tm
 from repro.core.algorithms import base
+from repro.obs.profile import scope
 
 
 class ScaffoldState(NamedTuple):
@@ -90,7 +91,8 @@ class Scaffold(base.FederatedAlgorithm):
             )
             return y, ci_new
 
-        y_final, ci_new = jax.vmap(local)(cids, c_i, keys)
+        with scope("local"):
+            y_final, ci_new = jax.vmap(local)(cids, c_i, keys)
         if comm is not None:
             k_comm = comm_lib.comm_key(key)
             y_final, comm = comm_lib.uplink(
@@ -157,7 +159,8 @@ class FedProx(base.FederatedAlgorithm):
             y, _ = jax.lax.scan(step, state.x, jax.random.split(kk, self.local_steps))
             return y
 
-        y_final = jax.vmap(local)(cids, keys)
+        with scope("local"):
+            y_final = jax.vmap(local)(cids, keys)
         y_mean = base.client_mean(state.x, y_final)
         x = tm.tree_lerp(self.server_lr, state.x, y_mean)
         return FedAvgState(x=x, eta=state.eta, r=state.r + 1)

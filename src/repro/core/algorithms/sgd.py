@@ -28,6 +28,7 @@ import jax.numpy as jnp
 
 from repro.core import tree_math as tm
 from repro.core.algorithms import base
+from repro.obs.profile import scope
 
 
 class SGDState(NamedTuple):
@@ -94,8 +95,9 @@ class SGD(base.FederatedAlgorithm):
             cids = base.sample_clients(k_sample, problem.num_clients, s)
             g_per = base.grad_k(problem, state.x, cids, k_grad, self.k)
             x = base.fused_server_step(state.x, g_per, state.eta)
-        decay = jnp.asarray(1.0 - state.eta * self.mu_avg)
-        tracker = state.tracker.update(x, jnp.clip(decay, 0.0, 1.0))
+        with scope("server"):
+            decay = jnp.asarray(1.0 - state.eta * self.mu_avg)
+            tracker = state.tracker.update(x, jnp.clip(decay, 0.0, 1.0))
         return SGDState(x=x, tracker=tracker, eta=state.eta, r=state.r + 1,
                         comm=comm)
 

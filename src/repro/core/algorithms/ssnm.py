@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from repro.core import tree_math as tm
 from repro.core.algorithms import base
+from repro.obs.profile import scope
 
 
 class SSNMState(NamedTuple):
@@ -65,6 +66,7 @@ class SSNM(base.FederatedAlgorithm):
         tau = self.tau if self.tau >= 0 else ((n / s) * eta * self.mu_h) / (1.0 + eta * self.mu_h)
         return eta, tau
 
+    @scope("local")
     def _tilde_grad_k(self, problem, y, cid, key):
         ks = jax.random.split(key, self.k)
         gs = jax.vmap(lambda kk: problem.grad_oracle(y, cid, kk))(ks)

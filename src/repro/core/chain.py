@@ -37,12 +37,30 @@ import numpy as np
 from repro.core import runner as runner_lib
 from repro.core import selection
 from repro.core import tree_math as tm
+from repro.obs.profile import scope
 
 # handoff modes (the transition INTO stage j, applied before its first round)
 _H_NONE = 0  # no handoff this round
 _H_ANCHOR = 1  # init from the anchor (a costed selection round already ran)
 _H_SELECT = 2  # inline selection between anchor and previous stage's output
 _H_TAKE = 3  # take the previous stage's output unconditionally
+
+
+@scope("select")
+def _reset_residuals(comm_st, hmd):
+    """Zero the error-feedback residuals on a handoff round (``hmd`` > 0).
+
+    They don't survive a stage handoff: the incoming stage's uplink payloads
+    have different semantics (iterate deltas vs gradients), and the residual
+    mass may belong to a trajectory selection just discarded; the
+    server-side downlink residual resets for the same reason (the selection
+    broadcast is full-precision, so clients hold the handoff point
+    exactly)."""
+    return comm_st._replace(
+        residual=jax.tree.map(lambda r: jnp.where(hmd > 0, 0.0, r),
+                              comm_st.residual),
+        down_residual=jax.tree.map(lambda r: jnp.where(hmd > 0, 0.0, r),
+                                   comm_st.down_residual))
 
 
 @dataclasses.dataclass
@@ -222,6 +240,7 @@ class Chain:
                  else problem.num_clients)
         sel_k = self.selection_k
 
+        @scope("select")
         def _select2(p, anchor, cand, k_sel):
             """Lemma H.2 pick between the anchor and a candidate; True = kept
             the anchor (argmin ties resolve to the anchor, as the seed did)."""
@@ -290,6 +309,7 @@ class Chain:
             return jax.lax.switch(j, [branch(i) for i in range(n)],
                                   (states, comm_st, k_round, scale, mask))
 
+        @scope("select")
         def _handoff(p, states, anchor, sid, hmd, k_sel):
             def do_handoff(args):
                 states, anchor = args
@@ -342,13 +362,13 @@ class Chain:
                 states, anchor = args
                 cand = ops.output(sid, states)
                 best, kept = ops.select2(p, anchor, cand, k_sel)
-                sub = p.global_loss(best) - f_star
+                sub = runner_lib.evaluate(p, best, f_star)
                 return states, best, sub, kept
 
             def alg_round(args):
                 states, anchor = args
                 states = ops.round(p, sid, states, k_round, scale)
-                sub = p.global_loss(ops.output(sid, states)) - f_star
+                sub = runner_lib.evaluate(p, ops.output(sid, states), f_star)
                 return states, anchor, sub, jnp.asarray(False)
 
             states, anchor, sub, s_kept = jax.lax.cond(
@@ -415,13 +435,11 @@ class Chain:
 
             def executor(spec, x0, states0, key, eta_scale):
                 from repro.core.algorithms import base as algo_base
-                from repro.obs import events as obs_events
 
                 p = resolve(spec)
                 for st in states0:
                     algo_base.audit_state(st)  # protocol check, once per trace
                 runner_lib.TRACE_COUNTS[f"chain/{self.name}"] += 1
-                obs_events.TRACE_EVENTS[f"chain/{self.name}"] += 1
                 f_star = runner_lib.f_star_operand(p)
                 keys_r, keys_s = self._derive_keys(sched, key)
 
@@ -441,14 +459,12 @@ class Chain:
             def executor(spec, x0, states0, key, eta_scale, masks, comm0):
                 from repro.comm import config as comm_cfg
                 from repro.core.algorithms import base as algo_base
-                from repro.obs import events as obs_events
                 from repro.obs import telemetry as obs_tel
 
                 p = resolve(spec)
                 for st in states0:
                     algo_base.audit_state(st)
                 runner_lib.TRACE_COUNTS[f"chain-comm/{self.name}"] += 1
-                obs_events.TRACE_EVENTS[f"chain-comm/{self.name}"] += 1
                 f_star = runner_lib.f_star_operand(p)
                 keys_r, keys_s = self._derive_keys(sched, key)
                 # selection broadcasts the whole parameter pytree (leaf dims
@@ -458,22 +474,8 @@ class Chain:
                 def body(carry, xs):
                     states, anchor, comm_st = carry
                     k_round, k_sel, sid, knd, hmd, scale, mask = xs
-                    comm_st = comm_cfg.zero_round_bits(comm_st)
-                    # error-feedback residuals don't survive a stage
-                    # handoff: the incoming stage's uplink payloads have
-                    # different semantics (iterate deltas vs gradients), and
-                    # the residual mass may belong to a trajectory selection
-                    # just discarded; the server-side downlink residual
-                    # resets for the same reason (the selection broadcast is
-                    # full-precision, so clients hold the handoff point
-                    # exactly)
-                    comm_st = comm_st._replace(
-                        residual=jax.tree.map(
-                            lambda r: jnp.where(hmd > 0, 0.0, r),
-                            comm_st.residual),
-                        down_residual=jax.tree.map(
-                            lambda r: jnp.where(hmd > 0, 0.0, r),
-                            comm_st.down_residual))
+                    comm_st = _reset_residuals(
+                        comm_cfg.zero_round_bits(comm_st), hmd)
                     states, anchor, h_kept = ops.handoff(
                         p, states, anchor, sid, hmd, k_sel)
                     prev_x = (ops.stage_x(sid, states)
@@ -483,14 +485,15 @@ class Chain:
                         states, anchor, comm_st = args
                         cand = ops.output(sid, states)
                         best, kept = ops.select2(p, anchor, cand, k_sel)
-                        sub = p.global_loss(best) - f_star
+                        sub = runner_lib.evaluate(p, best, f_star)
                         return states, best, comm_st, sub, kept
 
                     def alg_round(args):
                         states, anchor, comm_st = args
                         states, comm_st = ops.round_comm(
                             p, sid, states, comm_st, k_round, scale, mask)
-                        sub = p.global_loss(ops.output(sid, states)) - f_star
+                        sub = runner_lib.evaluate(
+                            p, ops.output(sid, states), f_star)
                         return states, anchor, comm_st, sub, jnp.asarray(False)
 
                     states, anchor, comm_st, sub, s_kept = jax.lax.cond(
@@ -598,7 +601,6 @@ class Chain:
                      pstate0, comm0):
             from repro.comm import config as comm_cfg
             from repro.core.algorithms import base as algo_base
-            from repro.obs import events as obs_events
             from repro.obs import telemetry as obs_tel
             from repro.selection import policies as pol
 
@@ -606,7 +608,6 @@ class Chain:
             for st in states0:
                 algo_base.audit_state(st)
             runner_lib.TRACE_COUNTS[f"chain-sel/{self.name}"] += 1
-            obs_events.TRACE_EVENTS[f"chain-sel/{self.name}"] += 1
             f_star = runner_lib.f_star_operand(p)
             keys_r, keys_s = self._derive_keys(sched, key)
             sel_up, sel_down = comm_cfg.selection_round_bits(x0, sel_s)
@@ -615,14 +616,8 @@ class Chain:
             def body(carry, xs):
                 states, anchor, comm_st, pstate = carry
                 k_round, k_sel, sid, knd, hmd, scale, k_pol = xs
-                comm_st = comm_cfg.zero_round_bits(comm_st)
-                comm_st = comm_st._replace(
-                    residual=jax.tree.map(
-                        lambda r: jnp.where(hmd > 0, 0.0, r),
-                        comm_st.residual),
-                    down_residual=jax.tree.map(
-                        lambda r: jnp.where(hmd > 0, 0.0, r),
-                        comm_st.down_residual))
+                comm_st = _reset_residuals(
+                    comm_cfg.zero_round_bits(comm_st), hmd)
                 states, anchor, h_kept = ops.handoff(
                     p, states, anchor, sid, hmd, k_sel)
                 prev_x = (ops.stage_x(sid, states) if telemetry is not None
@@ -634,14 +629,15 @@ class Chain:
                     states, anchor, comm_st = args
                     cand = ops.output(sid, states)
                     best, kept = ops.select2(p, anchor, cand, k_sel)
-                    sub = p.global_loss(best) - f_star
+                    sub = runner_lib.evaluate(p, best, f_star)
                     return states, best, comm_st, sub, kept
 
                 def alg_round(args):
                     states, anchor, comm_st = args
                     states, comm_st = ops.round_comm(
                         p, sid, states, comm_st, k_round, scale, mask)
-                    sub = p.global_loss(ops.output(sid, states)) - f_star
+                    sub = runner_lib.evaluate(
+                        p, ops.output(sid, states), f_star)
                     return states, anchor, comm_st, sub, jnp.asarray(False)
 
                 states, anchor, comm_st, sub, s_kept = jax.lax.cond(
@@ -718,13 +714,11 @@ class Chain:
         def executor(spec, x0, states0, keys_r, keys_s, stage_id, kind,
                      hmode, eta_scale):
             from repro.core.algorithms import base as algo_base
-            from repro.obs import events as obs_events
 
             p = resolve(spec)
             for st in states0:
                 algo_base.audit_state(st)
             runner_lib.TRACE_COUNTS[f"chain-frac/{self.name}"] += 1
-            obs_events.TRACE_EVENTS[f"chain-frac/{self.name}"] += 1
             f_star = runner_lib.f_star_operand(p)
 
             (states, _), (history, kept_flags) = jax.lax.scan(

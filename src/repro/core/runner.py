@@ -46,6 +46,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.obs.profile import scope
+
 # The jitted executors donate their call-private operands (scan-carry
 # state0, per-cell key/mask stacks — see core.sweep's memory model). CPU
 # has no donation support, so JAX warns once per call that the donated
@@ -217,6 +219,13 @@ def f_star_operand(problem):
     return problem.f_star if problem.f_star is not None else 0.0
 
 
+@scope("eval")
+def evaluate(p, x, f_star):
+    """F(x) − F*: what every executor records per round (and the sweep
+    cells once more at the end)."""
+    return p.global_loss(x) - f_star
+
+
 def _obs_emit(kind, **fields):
     """Forward one cache/compile event to the obs event log — a None-check
     no-op unless ``repro.obs.events`` has a recorder installed."""
@@ -302,13 +311,11 @@ def executor_body(algo, problem, eval_output: bool = True, telemetry=None):
 
     def executor(spec, state0, keys, eta_scale):
         from repro.core.algorithms import base as algo_base
-        from repro.obs import events as obs_events
         from repro.obs import telemetry as obs_tel
 
         p = resolve(spec)
         algo_base.audit_state(state0)  # protocol check, once per trace
         TRACE_COUNTS[f"runner/{algo.name}"] += 1  # trace-time side effect
-        obs_events.TRACE_EVENTS[f"runner/{algo.name}"] += 1
         f_star = f_star_operand(p)
         base_eta = state0.eta
 
@@ -317,7 +324,7 @@ def executor_body(algo, problem, eval_output: bool = True, telemetry=None):
             st = algo.round(p, state._replace(eta=base_eta * scale), k)
             st = st._replace(eta=base_eta)  # executor owns annealing
             x_eval = algo.output(st) if eval_output else st.x
-            sub = p.global_loss(x_eval) - f_star
+            sub = evaluate(p, x_eval, f_star)
             if telemetry is None:
                 return st, sub
             taps = obs_tel.round_taps(
@@ -373,14 +380,12 @@ def comm_executor_body(algo, problem, eval_output: bool = True,
     def executor(spec, state0, keys, eta_scale, masks):
         from repro.comm import config as comm_cfg
         from repro.core.algorithms import base as algo_base
-        from repro.obs import events as obs_events
         from repro.obs import telemetry as obs_tel
 
         p = resolve(spec)
         algo_base.audit_state(state0)
         comm_cfg.comm_state_or_error(state0, algo.name)
         TRACE_COUNTS[f"runner-comm/{algo.name}"] += 1
-        obs_events.TRACE_EVENTS[f"runner-comm/{algo.name}"] += 1
         f_star = f_star_operand(p)
         base_eta = state0.eta
 
@@ -393,7 +398,7 @@ def comm_executor_body(algo, problem, eval_output: bool = True,
             comm = comm_cfg.comm_state_or_error(st, algo.name)
             st = st._replace(eta=base_eta)
             x_eval = algo.output(st) if eval_output else st.x
-            sub = p.global_loss(x_eval) - f_star
+            sub = evaluate(p, x_eval, f_star)
             if telemetry is None:
                 return st, (sub, comm.bits_up, comm.bits_down)
             taps = obs_tel.round_taps(
@@ -450,7 +455,6 @@ def selection_executor_body(algo, problem, eval_output: bool = True,
     def executor(spec, state0, keys, eta_scale, sel_keys, pparams, pstate0):
         from repro.comm import config as comm_cfg
         from repro.core.algorithms import base as algo_base
-        from repro.obs import events as obs_events
         from repro.obs import telemetry as obs_tel
         from repro.selection import policies as pol
 
@@ -458,7 +462,6 @@ def selection_executor_body(algo, problem, eval_output: bool = True,
         algo_base.audit_state(state0)
         comm_cfg.comm_state_or_error(state0, algo.name)
         TRACE_COUNTS[f"runner-sel/{algo.name}"] += 1
-        obs_events.TRACE_EVENTS[f"runner-sel/{algo.name}"] += 1
         f_star = f_star_operand(p)
         base_eta = state0.eta
         extra_up = pol.probe_bits(pparams, p.num_clients)
@@ -476,7 +479,7 @@ def selection_executor_body(algo, problem, eval_output: bool = True,
             comm = comm._replace(bits_up=comm.bits_up + extra_up)
             st = st._replace(eta=base_eta, comm=comm)
             x_eval = algo.output(st) if eval_output else st.x
-            sub = p.global_loss(x_eval) - f_star
+            sub = evaluate(p, x_eval, f_star)
             if telemetry is None:
                 return (st, pstate), (sub, comm.bits_up, comm.bits_down,
                                       mask)
@@ -514,12 +517,10 @@ def method_executor_body(methods, problem, eval_output: bool = True):
 
     def executor(spec, state0, keys, eta_scale, midx):
         from repro.core.algorithms import base as algo_base
-        from repro.obs import events as obs_events
 
         p = resolve(spec)
         algo_base.audit_state(state0)
         TRACE_COUNTS[f"runner-methods/{tag}"] += 1
-        obs_events.TRACE_EVENTS[f"runner-methods/{tag}"] += 1
         f_star = f_star_operand(p)
         base_eta = state0.eta
 
@@ -538,7 +539,7 @@ def method_executor_body(methods, problem, eval_output: bool = True):
                  for m in methods],
                 (st_in, k))
             st = st._replace(eta=base_eta)
-            sub = p.global_loss(_output(st)) - f_star
+            sub = evaluate(p, _output(st), f_star)
             return st, sub
 
         return jax.lax.scan(one_round, state0, (keys, eta_scale))

@@ -116,6 +116,7 @@ numeric knobs (ζ, σ, compressor, …) never force a new one.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Optional, Sequence
 
 import jax
@@ -125,6 +126,7 @@ import numpy as np
 from repro.core import chain as chain_lib
 from repro.core import runner as runner_lib
 from repro.core import tree_math as tm
+from repro.obs.profile import annotate
 
 
 @dataclasses.dataclass
@@ -171,7 +173,6 @@ def make_algo_cell(algo, problem, rounds: int, eval_output: bool,
     ``telemetry`` (``repro.obs.Telemetry``) appends the per-round taps dict
     as a trailing output.
     """
-    from repro.obs import events as obs_events
 
     body = runner_lib.executor_body(algo, problem, eval_output, telemetry)
     _, resolve = runner_lib._bind(problem)
@@ -180,7 +181,6 @@ def make_algo_cell(algo, problem, rounds: int, eval_output: bool,
     def cell(spec, x0, key, eta):
         p = resolve(spec)
         runner_lib.TRACE_COUNTS[f"{tag}/{algo.name}"] += 1
-        obs_events.TRACE_EVENTS[f"{tag}/{algo.name}"] += 1
         state0 = algo.init(p, x0)
         new_eta = (state0.eta * eta if eta_mode == "scale"
                    else jnp.asarray(eta, jnp.result_type(state0.eta)))
@@ -191,7 +191,7 @@ def make_algo_cell(algo, problem, rounds: int, eval_output: bool,
         else:
             state, (history, taps) = body(spec, state0, keys, eta_scale)
         x_hat = algo.output(state)
-        sub = p.global_loss(x_hat) - runner_lib.f_star_operand(p)
+        sub = runner_lib.evaluate(p, x_hat, runner_lib.f_star_operand(p))
         if telemetry is None:
             return x_hat, history, sub
         return x_hat, history, sub, taps
@@ -202,7 +202,6 @@ def make_algo_cell(algo, problem, rounds: int, eval_output: bool,
 def make_algo_comm_cell(algo, problem, rounds: int, eval_output: bool,
                         eta_mode: str, tag: str, telemetry=None):
     """Comm-enabled cell: ``cell(spec, x0, key, eta, masks, comm0)``."""
-    from repro.obs import events as obs_events
 
     body = runner_lib.comm_executor_body(algo, problem, eval_output,
                                          telemetry)
@@ -212,7 +211,6 @@ def make_algo_comm_cell(algo, problem, rounds: int, eval_output: bool,
     def cell(spec, x0, key, eta, masks, comm0):
         p = resolve(spec)
         runner_lib.TRACE_COUNTS[f"{tag}/{algo.name}"] += 1
-        obs_events.TRACE_EVENTS[f"{tag}/{algo.name}"] += 1
         state0 = algo.init(p, x0)
         new_eta = (state0.eta * eta if eta_mode == "scale"
                    else jnp.asarray(eta, jnp.result_type(state0.eta)))
@@ -225,7 +223,7 @@ def make_algo_comm_cell(algo, problem, rounds: int, eval_output: bool,
             state, (history, bits_up, bits_down, taps) = body(
                 spec, state0, keys, eta_scale, masks)
         x_hat = algo.output(state)
-        sub = p.global_loss(x_hat) - runner_lib.f_star_operand(p)
+        sub = runner_lib.evaluate(p, x_hat, runner_lib.f_star_operand(p))
         if telemetry is None:
             return x_hat, history, sub, bits_up, bits_down
         return x_hat, history, sub, bits_up, bits_down, taps
@@ -235,7 +233,6 @@ def make_algo_comm_cell(algo, problem, rounds: int, eval_output: bool,
 
 def make_chain_cell(chain, problem, rounds: int, tag: str, telemetry=None):
     """Chain cell: ``cell(spec, x0, key, mult, eta_scale)``."""
-    from repro.obs import events as obs_events
 
     body = chain.executor_body(problem, rounds, telemetry=telemetry)
     _, resolve = runner_lib._bind(problem)
@@ -244,14 +241,13 @@ def make_chain_cell(chain, problem, rounds: int, tag: str, telemetry=None):
     def cell(spec, x0, key, mult, eta_scale):
         p = resolve(spec)
         runner_lib.TRACE_COUNTS[f"{tag}/{chain.name}"] += 1
-        obs_events.TRACE_EVENTS[f"{tag}/{chain.name}"] += 1
         states0 = chain.init_states(p, x0, eta_scale=mult)
         if telemetry is None:
             x_hat, history, kept = body(spec, x0, states0, key, eta_scale)
         else:
             x_hat, history, kept, taps = body(spec, x0, states0, key,
                                               eta_scale)
-        sub = p.global_loss(x_hat) - runner_lib.f_star_operand(p)
+        sub = runner_lib.evaluate(p, x_hat, runner_lib.f_star_operand(p))
         if telemetry is None:
             return x_hat, history, sub, kept[sel_idx]
         return x_hat, history, sub, kept[sel_idx], taps
@@ -263,7 +259,6 @@ def make_chain_comm_cell(chain, problem, rounds: int, tag: str,
                          telemetry=None):
     """Comm-enabled chain cell:
     ``cell(spec, x0, key, mult, eta_scale, masks, comm0)``."""
-    from repro.obs import events as obs_events
 
     body = chain.executor_body(problem, rounds, comm=True,
                                telemetry=telemetry)
@@ -273,7 +268,6 @@ def make_chain_comm_cell(chain, problem, rounds: int, tag: str,
     def cell(spec, x0, key, mult, eta_scale, masks, comm0):
         p = resolve(spec)
         runner_lib.TRACE_COUNTS[f"{tag}/{chain.name}"] += 1
-        obs_events.TRACE_EVENTS[f"{tag}/{chain.name}"] += 1
         states0 = chain.init_states(p, x0, eta_scale=mult)
         if telemetry is None:
             x_hat, history, kept, bits_up, bits_down = body(
@@ -281,7 +275,7 @@ def make_chain_comm_cell(chain, problem, rounds: int, tag: str,
         else:
             x_hat, history, kept, bits_up, bits_down, taps = body(
                 spec, x0, states0, key, eta_scale, masks, comm0)
-        sub = p.global_loss(x_hat) - runner_lib.f_star_operand(p)
+        sub = runner_lib.evaluate(p, x_hat, runner_lib.f_star_operand(p))
         if telemetry is None:
             return x_hat, history, sub, kept[sel_idx], bits_up, bits_down
         return (x_hat, history, sub, kept[sel_idx], bits_up, bits_down,
@@ -295,7 +289,6 @@ def make_chain_fraction_cell(chain, problem, rounds: int, tag: str):
     ``cell(spec, x0, keys_r, keys_s, stage_id, kind, hmode, eta_scale)``.
     Returns the FULL [R] kept-flags row (selection positions differ per
     fraction, so callers gather them per schedule)."""
-    from repro.obs import events as obs_events
 
     body = chain.fraction_executor_body(problem, rounds)
     _, resolve = runner_lib._bind(problem)
@@ -303,11 +296,10 @@ def make_chain_fraction_cell(chain, problem, rounds: int, tag: str):
     def cell(spec, x0, keys_r, keys_s, stage_id, kind, hmode, eta_scale):
         p = resolve(spec)
         runner_lib.TRACE_COUNTS[f"{tag}/{chain.name}"] += 1
-        obs_events.TRACE_EVENTS[f"{tag}/{chain.name}"] += 1
         states0 = chain.init_states(p, x0)
         x_hat, history, kept = body(spec, x0, states0, keys_r, keys_s,
                                     stage_id, kind, hmode, eta_scale)
-        sub = p.global_loss(x_hat) - runner_lib.f_star_operand(p)
+        sub = runner_lib.evaluate(p, x_hat, runner_lib.f_star_operand(p))
         return x_hat, history, sub, kept
 
     return cell
@@ -322,7 +314,6 @@ def make_selection_algo_cell(algo, problem, rounds: int, eval_output: bool,
     are leading operands so the policy-index adapter
     (``make_policy_cell``) can gather them per cell exactly like the
     problem stacks."""
-    from repro.obs import events as obs_events
 
     body = runner_lib.selection_executor_body(algo, problem, eval_output,
                                               telemetry)
@@ -332,7 +323,6 @@ def make_selection_algo_cell(algo, problem, rounds: int, eval_output: bool,
     def cell(spec, x0, pparams, pstate0, key, eta, sel_keys, comm0):
         p = resolve(spec)
         runner_lib.TRACE_COUNTS[f"{tag}/{algo.name}"] += 1
-        obs_events.TRACE_EVENTS[f"{tag}/{algo.name}"] += 1
         state0 = algo.init(p, x0)
         new_eta = (state0.eta * eta if eta_mode == "scale"
                    else jnp.asarray(eta, jnp.result_type(state0.eta)))
@@ -346,7 +336,7 @@ def make_selection_algo_cell(algo, problem, rounds: int, eval_output: bool,
                               taps) = body(
                 spec, state0, keys, eta_scale, sel_keys, pparams, pstate0)
         x_hat = algo.output(state)
-        sub = p.global_loss(x_hat) - runner_lib.f_star_operand(p)
+        sub = runner_lib.evaluate(p, x_hat, runner_lib.f_star_operand(p))
         if telemetry is None:
             return x_hat, history, sub, bits_up, bits_down, masks, pstate
         return (x_hat, history, sub, bits_up, bits_down, masks, pstate,
@@ -360,7 +350,6 @@ def make_selection_chain_cell(chain, problem, rounds: int, tag: str,
     """Policy-selection chain cell:
     ``cell(spec, x0, pparams, pstate0, key, mult, eta_sched, sel_keys,
     comm0)``."""
-    from repro.obs import events as obs_events
 
     body = chain.selection_executor_body(problem, rounds,
                                          telemetry=telemetry)
@@ -371,7 +360,6 @@ def make_selection_chain_cell(chain, problem, rounds: int, tag: str,
              comm0):
         p = resolve(spec)
         runner_lib.TRACE_COUNTS[f"{tag}/{chain.name}"] += 1
-        obs_events.TRACE_EVENTS[f"{tag}/{chain.name}"] += 1
         states0 = chain.init_states(p, x0, eta_scale=mult)
         if telemetry is None:
             x_hat, history, kept, bits_up, bits_down, masks, pstate = body(
@@ -382,7 +370,7 @@ def make_selection_chain_cell(chain, problem, rounds: int, tag: str,
              taps) = body(
                 spec, x0, states0, key, eta_sched, sel_keys, pparams,
                 pstate0, comm0)
-        sub = p.global_loss(x_hat) - runner_lib.f_star_operand(p)
+        sub = runner_lib.evaluate(p, x_hat, runner_lib.f_star_operand(p))
         if telemetry is None:
             return (x_hat, history, sub, kept[sel_idx], bits_up, bits_down,
                     masks, pstate)
@@ -671,7 +659,6 @@ def _sweep_fn_chain_decay(chain, problem, rounds: int):
     if fn is not None:
         return fn
 
-    from repro.obs import events as obs_events
 
     body = chain.executor_body(problem, rounds)  # SAME executor as run_sweep
     _, resolve = runner_lib._bind(problem)
@@ -679,10 +666,9 @@ def _sweep_fn_chain_decay(chain, problem, rounds: int):
     def cell(spec, x0, key, eta_scale):
         p = resolve(spec)
         runner_lib.TRACE_COUNTS[f"sweep-decay/{chain.name}"] += 1
-        obs_events.TRACE_EVENTS[f"sweep-decay/{chain.name}"] += 1
         states0 = chain.init_states(p, x0)
         x_hat, history, _ = body(spec, x0, states0, key, eta_scale)
-        sub = p.global_loss(x_hat) - runner_lib.f_star_operand(p)
+        sub = runner_lib.evaluate(p, x_hat, runner_lib.f_star_operand(p))
         return x_hat, history, sub
 
     # axes: seeds × decay grids (eta_scale rows)
@@ -700,7 +686,6 @@ def _sweep_fn_methods(methods, problem, rounds: int, eval_output: bool):
     if fn is not None:
         return fn
 
-    from repro.obs import events as obs_events
 
     body = runner_lib.method_executor_body(methods, problem, eval_output)
     _, resolve = runner_lib._bind(problem)
@@ -709,13 +694,12 @@ def _sweep_fn_methods(methods, problem, rounds: int, eval_output: bool):
     def cell(spec, x0, state0, key, eta, midx):
         p = resolve(spec)
         runner_lib.TRACE_COUNTS[f"sweep-methods/{tag}"] += 1
-        obs_events.TRACE_EVENTS[f"sweep-methods/{tag}"] += 1
         state0 = state0._replace(eta=state0.eta * eta)  # scale semantics
         keys = jax.random.split(key, rounds)
         state, history = body(spec, state0, keys, eta_scale, midx)
         x_hat = jax.lax.switch(
             midx, [lambda s, m=m: m.output(s) for m in methods], state)
-        sub = p.global_loss(x_hat) - runner_lib.f_star_operand(p)
+        sub = runner_lib.evaluate(p, x_hat, runner_lib.f_star_operand(p))
         return x_hat, history, sub
 
     grid = jax.vmap(jax.vmap(cell, in_axes=(None, None, None, None, 0, None)),
@@ -794,7 +778,12 @@ def _run_grid_sweep(algo_or_chain, problem, x0, rounds: int, *,
                     problems=None, mesh=None,
                     operand_layout: str = "indexed",
                     telemetry=None) -> SweepResult:
-    """The (seed, η) / (problem, seed, η) grid family — see ``run()``."""
+    """The (seed, η) / (problem, seed, η) grid family — see ``run()``.
+
+    The host path opens four spans in turn: ``repro.sweep.operands`` (keys,
+    η, the comm state, the η schedule, the problem operands),
+    ``repro.sweep.masks`` (the comm mask schedule), ``repro.sweep.executor``
+    (the cached executor) and ``repro.sweep.dispatch`` (its call)."""
     if mesh is not None:
         from repro.dist import grid as dist_grid
 
@@ -810,147 +799,80 @@ def _run_grid_sweep(algo_or_chain, problem, x0, rounds: int, *,
     etas = tuple(float(e) for e in etas)
     if not seeds:
         raise ValueError("run_sweep needs at least one seed")
-    keys = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
-    etas_arr = jnp.asarray(etas, jnp.float32)
+    if decay is not None and not is_chain:
+        raise NotImplementedError("decay sweeps: wrap the algorithm in a Chain")
+    n_seeds = len(seeds)
 
-    if problems is not None:
-        if decay is not None and not is_chain:
-            raise NotImplementedError(
-                "decay sweeps: wrap the algorithm in a Chain")
-        stacked, prob_names = _as_stacked_specs(problems)
-        n_probs = len(prob_names)
-        n_seeds = len(seeds)
-        x0_stack = _normalize_x0_stack(x0, stacked, n_probs)
-        # problems × seeds flatten to ONE cells axis, c = p·S + s (the
-        # contract of repro.dist.partition): keys tile per problem and the
-        # spec rides either as ONE O(P) stack + per-cell problem index
-        # (indexed layout, the default) or with every leaf repeated per
-        # seed (stacked layout, O(P·S)) — the exact per-cell values the
-        # sharded engine partitions over devices, so run_sweep(...,
-        # mesh=...) is bitwise identical to this path, and so are the two
-        # layouts to each other (module docstring: memory model).
-        spec_c, x0_c, pidx, keys_c = build_problem_operands(
-            stacked, x0_stack, keys, n_probs, n_seeds, operand_layout)
-        lead = ((spec_c, x0_c, pidx) if pidx is not None
-                else (spec_c, x0_c))
-
-        def grid_shape(outs):
-            return jax.tree.map(
-                lambda l: l.reshape((n_probs, n_seeds) + l.shape[1:]), outs)
-
+    with annotate("repro.sweep.operands"):
+        keys = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
+        etas_arr = jnp.asarray(etas, jnp.float32)
+        if problems is not None:
+            stacked, prob_names = _as_stacked_specs(problems)
+            n_probs = len(prob_names)
+            x0_stack = _normalize_x0_stack(x0, stacked, n_probs)
+            # problems × seeds flatten to ONE cells axis, c = p·S + s (the
+            # contract of repro.dist.partition): keys tile per problem and
+            # the spec rides either as ONE O(P) stack + per-cell problem
+            # index (indexed layout, the default) or with every leaf
+            # repeated per seed (stacked layout, O(P·S)) — the exact
+            # per-cell values the sharded engine partitions over devices,
+            # so run_sweep(..., mesh=...) is bitwise identical to this path,
+            # and so are the two layouts to each other (module docstring:
+            # memory model).
+            spec_c, x0_c, pidx, keys = build_problem_operands(
+                stacked, x0_stack, keys, n_probs, n_seeds, operand_layout)
+            lead = ((spec_c, x0_c) if pidx is None
+                    else (spec_c, x0_c, pidx))
+            # the executors are keyed on the stack's structure
+            structure, x0_one = stacked, tm.tree_index(x0_stack, 0)
+            axis = dict(problem_axis=True, layout=operand_layout)
+        else:
+            prob_names, n_probs = None, 1
+            lead = (runner_lib.as_spec(problem), x0)
+            structure, x0_one = problem, x0
+            axis = {}
+        tail = ((algo_or_chain.eta_schedule(rounds, decay),) if is_chain
+                else ())
         if comm is not None:
-            n_clients = stacked.num_clients
+            n_clients = structure.num_clients
+            comm0 = comm.init_state(n_clients, x0_one)
+
+    if comm is not None:
+        with annotate("repro.sweep.masks"):
             n_sched = (algo_or_chain.schedule_len(rounds) if is_chain
                        else rounds)
             # one independent [R, N] schedule per (problem, seed) cell:
-            # cell (p, s) uses the config's fold p·len(seeds) + s, so
-            # runner.run(..., comm_masks=round_masks(R, N, fold=p*S+s))
-            # reproduces it
+            # cell (p, s) uses the config's fold p·len(seeds) + s (s without
+            # a problem axis), so runner.run(..., comm_masks=round_masks(R,
+            # N, fold=p*S+s)) reproduces it
             masks = jnp.stack([
                 comm.round_masks(n_sched, n_clients, fold=p * n_seeds + s)
                 for p in range(n_probs) for s in range(n_seeds)])
-            comm0 = comm.init_state(n_clients, tm.tree_index(x0_stack, 0))
+        tail += (masks, comm0)
+
+    with annotate("repro.sweep.executor"):
         if is_chain:
-            chain = algo_or_chain
-            eta_sched = chain.eta_schedule(rounds, decay)
-            if comm is not None:
-                fn = _sweep_fn_chain_comm(chain, stacked, rounds,
-                                          problem_axis=True,
-                                          layout=operand_layout,
-                                          telemetry=telemetry)
-                outs, taps = _split_taps(grid_shape(
-                    fn(*lead, keys_c, etas_arr, eta_sched, masks, comm0)),
-                    telemetry)
-                x_hat, history, final, kept, bits_up, bits_down = outs
-                return SweepResult(history=history, final_sub=final,
-                                   x_hat=x_hat, seeds=seeds, etas=etas,
-                                   selected_initial=kept, bits_up=bits_up,
-                                   bits_down=bits_down, problems=prob_names,
-                                   diagnostics=taps)
-            fn = _sweep_fn_chain(chain, stacked, rounds, problem_axis=True,
-                                 layout=operand_layout, telemetry=telemetry)
-            outs, taps = _split_taps(grid_shape(
-                fn(*lead, keys_c, etas_arr, eta_sched)), telemetry)
-            x_hat, history, final, kept = outs
-            return SweepResult(history=history, final_sub=final, x_hat=x_hat,
-                               seeds=seeds, etas=etas, selected_initial=kept,
-                               problems=prob_names, diagnostics=taps)
-        if comm is not None:
-            fn = _sweep_fn_algo_comm(algo_or_chain, stacked, rounds,
-                                     eval_output, eta_mode,
-                                     problem_axis=True,
-                                     layout=operand_layout,
-                                     telemetry=telemetry)
-            outs, taps = _split_taps(grid_shape(
-                fn(*lead, keys_c, etas_arr, masks, comm0)), telemetry)
-            x_hat, history, final, bits_up, bits_down = outs
-            return SweepResult(history=history, final_sub=final, x_hat=x_hat,
-                               seeds=seeds, etas=etas, bits_up=bits_up,
-                               bits_down=bits_down, problems=prob_names,
-                               diagnostics=taps)
-        fn = _sweep_fn_algo(algo_or_chain, stacked, rounds, eval_output,
-                            eta_mode, problem_axis=True,
-                            layout=operand_layout, telemetry=telemetry)
-        outs, taps = _split_taps(grid_shape(
-            fn(*lead, keys_c, etas_arr)), telemetry)
-        x_hat, history, final = outs
-        return SweepResult(history=history, final_sub=final, x_hat=x_hat,
-                           seeds=seeds, etas=etas, problems=prob_names,
-                           diagnostics=taps)
+            fn = (_sweep_fn_chain if comm is None else _sweep_fn_chain_comm)(
+                algo_or_chain, structure, rounds, telemetry=telemetry, **axis)
+        else:
+            fn = (_sweep_fn_algo if comm is None else _sweep_fn_algo_comm)(
+                algo_or_chain, structure, rounds, eval_output, eta_mode,
+                telemetry=telemetry, **axis)
 
-    spec = runner_lib.as_spec(problem)
+    with annotate("repro.sweep.dispatch"):
+        outs = fn(*lead, keys, etas_arr, *tail)
 
-    if comm is not None:
-        n_clients = problem.num_clients
-        comm0 = comm.init_state(n_clients, x0)
-
-    if is_chain:
-        chain = algo_or_chain
-        eta_sched = chain.eta_schedule(rounds, decay)
-        if comm is not None:
-            n_sched = chain.schedule_len(rounds)
-            masks = jnp.stack([
-                comm.round_masks(n_sched, n_clients, fold=s)
-                for s in range(len(seeds))])
-            fn = _sweep_fn_chain_comm(chain, problem, rounds,
-                                      telemetry=telemetry)
-            outs, taps = _split_taps(
-                fn(spec, x0, keys, etas_arr, eta_sched, masks, comm0),
-                telemetry)
-            x_hat, history, final, kept, bits_up, bits_down = outs
-            return SweepResult(history=history, final_sub=final, x_hat=x_hat,
-                               seeds=seeds, etas=etas, selected_initial=kept,
-                               bits_up=bits_up, bits_down=bits_down,
-                               diagnostics=taps)
-        fn = _sweep_fn_chain(chain, problem, rounds, telemetry=telemetry)
-        outs, taps = _split_taps(
-            fn(spec, x0, keys, etas_arr, eta_sched), telemetry)
-        x_hat, history, final, kept = outs
-        return SweepResult(history=history, final_sub=final, x_hat=x_hat,
-                           seeds=seeds, etas=etas, selected_initial=kept,
-                           diagnostics=taps)
-
-    if decay is not None:
-        raise NotImplementedError("decay sweeps: wrap the algorithm in a Chain")
-    if comm is not None:
-        masks = jnp.stack([
-            comm.round_masks(rounds, n_clients, fold=s)
-            for s in range(len(seeds))])
-        fn = _sweep_fn_algo_comm(algo_or_chain, problem, rounds, eval_output,
-                                 eta_mode, telemetry=telemetry)
-        outs, taps = _split_taps(
-            fn(spec, x0, keys, etas_arr, masks, comm0), telemetry)
-        x_hat, history, final, bits_up, bits_down = outs
-        return SweepResult(history=history, final_sub=final, x_hat=x_hat,
-                           seeds=seeds, etas=etas,
-                           bits_up=bits_up, bits_down=bits_down,
-                           diagnostics=taps)
-    fn = _sweep_fn_algo(algo_or_chain, problem, rounds, eval_output, eta_mode,
-                        telemetry=telemetry)
-    outs, taps = _split_taps(fn(spec, x0, keys, etas_arr), telemetry)
-    x_hat, history, final = outs
+    if problems is not None:
+        outs = jax.tree.map(
+            lambda l: l.reshape((n_probs, n_seeds) + l.shape[1:]), outs)
+    outs, taps = _split_taps(outs, telemetry)
+    x_hat, history, final, *rest = outs
+    kept = rest.pop(0) if is_chain else None
+    bits_up, bits_down = rest if comm is not None else (None, None)
     return SweepResult(history=history, final_sub=final, x_hat=x_hat,
-                       seeds=seeds, etas=etas, diagnostics=taps)
+                       seeds=seeds, etas=etas, selected_initial=kept,
+                       bits_up=bits_up, bits_down=bits_down,
+                       problems=prob_names, diagnostics=taps)
 
 
 def run_method_sweep(methods, problem, x0, rounds: int, *,
@@ -1218,47 +1140,54 @@ class SweepRequest:
     telemetry: object = None
 
 
+# grid calls made by this process: the ``call`` argument of each
+# ``repro.sweep.run`` span, so a trace's spans group by call
+_GRID_CALLS = itertools.count(1)
+
+
 def run(req: SweepRequest) -> SweepResult:
     """Run the sweep family ``req`` describes — see ``SweepRequest`` for
     the operand axes. Returns a ``SweepResult`` (``SelectionSweepResult``
-    for the policy family)."""
-    families = [name for name, axis in (
-        ("decay_factors", req.decay_factors),
-        ("fractions", req.fractions),
-        ("policies", req.policies)) if axis is not None]
-    if len(families) > 1:
-        raise ValueError(
-            f"SweepRequest selects at most one sweep family; got "
-            f"{families} together")
-    if req.telemetry is not None and families not in ([], ["policies"]):
-        raise ValueError(
-            f"telemetry round taps are supported by the (seed, η) and "
-            f"policies sweep families, not {families[0]!r}")
-    if req.policies is not None:
-        from repro.selection import sweep as sel_sweep
+    for the policy family). The call is one ``repro.sweep.run`` host span
+    (argument ``call``, this process's grid-call sequence number)."""
+    with annotate("repro.sweep.run", call=next(_GRID_CALLS)):
+        families = [name for name, axis in (
+            ("decay_factors", req.decay_factors),
+            ("fractions", req.fractions),
+            ("policies", req.policies)) if axis is not None]
+        if len(families) > 1:
+            raise ValueError(
+                f"SweepRequest selects at most one sweep family; got "
+                f"{families} together")
+        if req.telemetry is not None and families not in ([], ["policies"]):
+            raise ValueError(
+                f"telemetry round taps are supported by the (seed, η) and "
+                f"policies sweep families, not {families[0]!r}")
+        if req.policies is not None:
+            from repro.selection import sweep as sel_sweep
 
-        return sel_sweep._run_selection_sweep(
+            return sel_sweep._run_selection_sweep(
+                req.algo_or_chain, req.problem, req.x0, req.rounds,
+                policies=req.policies, seeds=req.seeds, etas=req.etas,
+                eta_mode=req.eta_mode, comm=req.comm, problems=req.problems,
+                eval_output=req.eval_output, mesh=req.mesh,
+                telemetry=req.telemetry)
+        if req.fractions is not None:
+            return _run_fraction_sweep(
+                req.algo_or_chain, req.problem, req.x0, req.rounds,
+                seeds=req.seeds, fractions=req.fractions, decay=req.decay,
+                mesh=req.mesh)
+        if req.decay_factors is not None:
+            return _run_decay_sweep(
+                req.algo_or_chain, req.problem, req.x0, req.rounds,
+                seeds=req.seeds, decay_factors=req.decay_factors,
+                decay_first=req.decay_first)
+        return _run_grid_sweep(
             req.algo_or_chain, req.problem, req.x0, req.rounds,
-            policies=req.policies, seeds=req.seeds, etas=req.etas,
-            eta_mode=req.eta_mode, comm=req.comm, problems=req.problems,
-            eval_output=req.eval_output, mesh=req.mesh,
-            telemetry=req.telemetry)
-    if req.fractions is not None:
-        return _run_fraction_sweep(
-            req.algo_or_chain, req.problem, req.x0, req.rounds,
-            seeds=req.seeds, fractions=req.fractions, decay=req.decay,
-            mesh=req.mesh)
-    if req.decay_factors is not None:
-        return _run_decay_sweep(
-            req.algo_or_chain, req.problem, req.x0, req.rounds,
-            seeds=req.seeds, decay_factors=req.decay_factors,
-            decay_first=req.decay_first)
-    return _run_grid_sweep(
-        req.algo_or_chain, req.problem, req.x0, req.rounds,
-        seeds=req.seeds, etas=req.etas, eta_mode=req.eta_mode,
-        eval_output=req.eval_output, decay=req.decay, comm=req.comm,
-        problems=req.problems, mesh=req.mesh,
-        operand_layout=req.operand_layout, telemetry=req.telemetry)
+            seeds=req.seeds, etas=req.etas, eta_mode=req.eta_mode,
+            eval_output=req.eval_output, decay=req.decay, comm=req.comm,
+            problems=req.problems, mesh=req.mesh,
+            operand_layout=req.operand_layout, telemetry=req.telemetry)
 
 
 def run_sweep(algo_or_chain, problem, x0, rounds: int, *,

@@ -53,6 +53,7 @@ from repro.core import runner as runner_lib
 from repro.core import sweep as sweep_lib
 from repro.core import tree_math as tm
 from repro.dist import mesh as mesh_lib, partition
+from repro.obs.profile import annotate
 from repro.sharding import rules as rules_lib
 
 
@@ -190,153 +191,127 @@ def run_sweep_sharded(algo_or_chain, problem, x0, rounds: int, *,
     if decay is not None and not is_chain:
         raise NotImplementedError(
             "decay sweeps: wrap the algorithm in a Chain")
-    n_shards = mesh_lib.grid_size(mesh)
-    keys = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
-    etas_arr = jnp.asarray(etas, jnp.float32)
     n_seeds = len(seeds)
 
-    per_cell = problems is not None  # spec/x0 stacked per cell, or replicated
-    if per_cell:
-        stacked, prob_names = sweep_lib._as_stacked_specs(problems)
-        n_probs = len(prob_names)
-        x0_stack = sweep_lib._normalize_x0_stack(x0, stacked, n_probs)
-        lead_shape = (n_probs, n_seeds)
-    else:
-        stacked = _require_spec(problem)
-        prob_names = None
-        n_probs = 1
-        lead_shape = (n_seeds,)
+    with annotate("repro.sweep.operands"):
+        n_shards = mesh_lib.grid_size(mesh)
+        keys = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
+        etas_arr = jnp.asarray(etas, jnp.float32)
 
-    n_cells = n_probs * n_seeds
-    src_idx, _ = partition.pad_cells(n_cells, n_shards)
-    idx = jnp.asarray(src_idx)
-    p_idx, s_idx = partition.cell_coords(n_probs, n_seeds)
-    keys_c = keys[jnp.asarray(s_idx)][idx]  # [C_pad, 2]
+        # spec/x0 stacked per cell, or replicated
+        per_cell = problems is not None
+        if per_cell:
+            stacked, prob_names = sweep_lib._as_stacked_specs(problems)
+            n_probs = len(prob_names)
+            x0_stack = sweep_lib._normalize_x0_stack(x0, stacked, n_probs)
+            lead_shape = (n_probs, n_seeds)
+        else:
+            stacked = _require_spec(problem)
+            prob_names = None
+            n_probs = 1
+            lead_shape = (n_seeds,)
 
-    indexed = per_cell and operand_layout == "indexed"
-    if indexed:
-        # O(P) layout: the stacks ride replicated, the in-cell gather is
-        # driven by the sharded per-cell problem index
-        spec_c, x0_c = stacked, x0_stack
-        pidx_c = jnp.asarray(p_idx, jnp.int32)[idx]
-    elif per_cell:
-        spec_c = _gather_cells(stacked, jnp.asarray(p_idx)[idx])
-        x0_c = _gather_cells(x0_stack, jnp.asarray(p_idx)[idx])
-    else:
-        spec_c, x0_c = stacked, x0  # replicated: the single-device layout
+        n_cells = n_probs * n_seeds
+        src_idx, _ = partition.pad_cells(n_cells, n_shards)
+        idx = jnp.asarray(src_idx)
+        p_idx, s_idx = partition.cell_coords(n_probs, n_seeds)
+        keys_c = keys[jnp.asarray(s_idx)][idx]  # [C_pad, 2]
+
+        indexed = per_cell and operand_layout == "indexed"
+        if indexed:
+            # O(P) layout: the stacks ride replicated, the in-cell gather
+            # is driven by the sharded per-cell problem index
+            spec_c, x0_c = stacked, x0_stack
+            pidx_c = jnp.asarray(p_idx, jnp.int32)[idx]
+        elif per_cell:
+            spec_c = _gather_cells(stacked, jnp.asarray(p_idx)[idx])
+            x0_c = _gather_cells(x0_stack, jnp.asarray(p_idx)[idx])
+        else:
+            spec_c, x0_c = stacked, x0  # replicated: the single-device layout
+
+        tail = ((algo_or_chain.eta_schedule(rounds, decay),) if is_chain
+                else ())
+        if comm is not None:
+            n_clients = stacked.num_clients
+            comm0 = comm.init_state(
+                n_clients, tm.tree_index(x0_stack, 0) if per_cell else x0)
 
     if comm is not None:
-        n_clients = stacked.num_clients
-        n_sched = (algo_or_chain.schedule_len(rounds) if is_chain else rounds)
-        # per-cell [R, N] schedules; fold p·S + s == s when there is no
-        # problems axis — exactly the single-device folds, cell for cell
-        masks_flat = jnp.stack([
-            comm.round_masks(n_sched, n_clients,
-                             fold=partition.flatten_cell(p, s, n_seeds))
-            for p in range(n_probs) for s in range(n_seeds)])
-        masks_c = masks_flat[idx]
-        comm0 = comm.init_state(
-            n_clients, tm.tree_index(x0_stack, 0) if per_cell else x0)
+        with annotate("repro.sweep.masks"):
+            n_sched = (algo_or_chain.schedule_len(rounds) if is_chain
+                       else rounds)
+            # per-cell [R, N] schedules; fold p·S + s == s when there is no
+            # problems axis — exactly the single-device folds, cell for cell
+            masks_flat = jnp.stack([
+                comm.round_masks(n_sched, n_clients,
+                                 fold=partition.flatten_cell(p, s, n_seeds))
+                for p in range(n_probs) for s in range(n_seeds)])
+            tail += (masks_flat[idx], comm0)
 
     rep = not per_cell  # spec/x0 replication flag (stacked layout)
     name_tag = "dist-comm" if comm is not None else "dist"
     if per_cell:
         name_tag += "-probs"
     pkey = runner_lib.problem_key(stacked)
+    layout_key = operand_layout if per_cell else None
 
-    def plan(cell, cell_in_axes, replicated_args):
-        """(cell, axes, replication, operand prefix, donated argnums) for
-        the chosen layout — donation covers every call-private stack
-        (keys/masks/η rows/pidx/comm0), never the caller-owned spec/x0."""
+    # (cell, axes, replication, cache key) of the family; donation covers
+    # every call-private stack (keys/masks/η rows/pidx/comm0), never the
+    # caller-owned spec/x0
+    with annotate("repro.sweep.executor"):
+        if is_chain:
+            chain = algo_or_chain
+            if comm is not None:
+                cell = sweep_lib.make_chain_comm_cell(
+                    chain, stacked, rounds, name_tag, telemetry)
+                axes = (None, None, None, 0, None, None, None)
+                reps = (rep, rep, False, True, True, False, True)
+                key = ("dist-chain-comm", chain._key(), pkey, rounds,
+                       per_cell, layout_key, telemetry)
+            else:
+                cell = sweep_lib.make_chain_cell(chain, stacked, rounds,
+                                                 name_tag, telemetry)
+                axes = (None, None, None, 0, None)
+                reps = (rep, rep, False, True, True)
+                key = ("dist-chain", chain._key(), pkey, rounds, per_cell,
+                       layout_key, telemetry)
+        elif comm is not None:
+            cell = sweep_lib.make_algo_comm_cell(
+                algo_or_chain, stacked, rounds, eval_output, eta_mode,
+                name_tag, telemetry)
+            axes = (None, None, None, 0, None, None)
+            reps = (rep, rep, False, True, False, True)
+            key = ("dist-algo-comm", algo_or_chain, pkey, rounds,
+                   eval_output, eta_mode, per_cell, layout_key, telemetry)
+        else:
+            cell = sweep_lib.make_algo_cell(
+                algo_or_chain, stacked, rounds, eval_output, eta_mode,
+                name_tag, telemetry)
+            axes = (None, None, None, 0)
+            reps = (rep, rep, False, True)
+            key = ("dist-algo", algo_or_chain, pkey, rounds, eval_output,
+                   eta_mode, per_cell, layout_key, telemetry)
         if indexed:
-            cell, cell_in_axes, replicated_args = _indexed_cell_plan(
-                cell, cell_in_axes, replicated_args)
+            cell, axes, reps = _indexed_cell_plan(cell, axes, reps)
             lead = (spec_c, x0_c, pidx_c)
         else:
             lead = (spec_c, x0_c)
-        donate = tuple(range(2, len(replicated_args)))
-        return cell, cell_in_axes, replicated_args, lead, donate
+        fn = _sharded_grid_fn(key, mesh, cell, cell_in_axes=axes,
+                              replicated_args=reps,
+                              donate_argnums=tuple(range(2, len(reps))))
 
-    layout_key = operand_layout if per_cell else None
-    if is_chain:
-        chain = algo_or_chain
-        eta_sched = chain.eta_schedule(rounds, decay)
-        if comm is not None:
-            cell, axes, reps, lead, donate = plan(
-                sweep_lib.make_chain_comm_cell(chain, stacked, rounds,
-                                               name_tag, telemetry),
-                (None, None, None, 0, None, None, None),
-                (rep, rep, False, True, True, False, True))
-            fn = _sharded_grid_fn(
-                ("dist-chain-comm", chain._key(), pkey, rounds, per_cell,
-                 layout_key, telemetry),
-                mesh, cell, cell_in_axes=axes, replicated_args=reps,
-                donate_argnums=donate)
-            outs, taps = sweep_lib._split_taps(_unpad_cells(
-                fn(*lead, keys_c, etas_arr, eta_sched, masks_c, comm0),
-                n_cells, lead_shape), telemetry)
-            x_hat, history, final, kept, bits_up, bits_down = outs
-            return sweep_lib.SweepResult(
-                history=history, final_sub=final, x_hat=x_hat, seeds=seeds,
-                etas=etas, selected_initial=kept, bits_up=bits_up,
-                bits_down=bits_down, problems=prob_names, diagnostics=taps)
-        cell, axes, reps, lead, donate = plan(
-            sweep_lib.make_chain_cell(chain, stacked, rounds, name_tag,
-                                      telemetry),
-            (None, None, None, 0, None),
-            (rep, rep, False, True, True))
-        fn = _sharded_grid_fn(
-            ("dist-chain", chain._key(), pkey, rounds, per_cell, layout_key,
-             telemetry),
-            mesh, cell, cell_in_axes=axes, replicated_args=reps,
-            donate_argnums=donate)
-        outs, taps = sweep_lib._split_taps(_unpad_cells(
-            fn(*lead, keys_c, etas_arr, eta_sched), n_cells, lead_shape),
-            telemetry)
-        x_hat, history, final, kept = outs
-        return sweep_lib.SweepResult(
-            history=history, final_sub=final, x_hat=x_hat, seeds=seeds,
-            etas=etas, selected_initial=kept, problems=prob_names,
-            diagnostics=taps)
+    with annotate("repro.sweep.dispatch"):
+        outs = fn(*lead, keys_c, etas_arr, *tail)
 
-    algo = algo_or_chain
-    if comm is not None:
-        cell, axes, reps, lead, donate = plan(
-            sweep_lib.make_algo_comm_cell(
-                algo, stacked, rounds, eval_output, eta_mode, name_tag,
-                telemetry),
-            (None, None, None, 0, None, None),
-            (rep, rep, False, True, False, True))
-        fn = _sharded_grid_fn(
-            ("dist-algo-comm", algo, pkey, rounds, eval_output, eta_mode,
-             per_cell, layout_key, telemetry),
-            mesh, cell, cell_in_axes=axes, replicated_args=reps,
-            donate_argnums=donate)
-        outs, taps = sweep_lib._split_taps(_unpad_cells(
-            fn(*lead, keys_c, etas_arr, masks_c, comm0), n_cells,
-            lead_shape), telemetry)
-        x_hat, history, final, bits_up, bits_down = outs
-        return sweep_lib.SweepResult(
-            history=history, final_sub=final, x_hat=x_hat, seeds=seeds,
-            etas=etas, bits_up=bits_up, bits_down=bits_down,
-            problems=prob_names, diagnostics=taps)
-    cell, axes, reps, lead, donate = plan(
-        sweep_lib.make_algo_cell(
-            algo, stacked, rounds, eval_output, eta_mode, name_tag,
-            telemetry),
-        (None, None, None, 0),
-        (rep, rep, False, True))
-    fn = _sharded_grid_fn(
-        ("dist-algo", algo, pkey, rounds, eval_output, eta_mode, per_cell,
-         layout_key, telemetry),
-        mesh, cell, cell_in_axes=axes, replicated_args=reps,
-        donate_argnums=donate)
-    outs, taps = sweep_lib._split_taps(_unpad_cells(
-        fn(*lead, keys_c, etas_arr), n_cells, lead_shape), telemetry)
-    x_hat, history, final = outs
-    return sweep_lib.SweepResult(history=history, final_sub=final,
-                                 x_hat=x_hat, seeds=seeds, etas=etas,
-                                 problems=prob_names, diagnostics=taps)
+    outs, taps = sweep_lib._split_taps(
+        _unpad_cells(outs, n_cells, lead_shape), telemetry)
+    x_hat, history, final, *rest = outs
+    kept = rest.pop(0) if is_chain else None
+    bits_up, bits_down = rest if comm is not None else (None, None)
+    return sweep_lib.SweepResult(
+        history=history, final_sub=final, x_hat=x_hat, seeds=seeds,
+        etas=etas, selected_initial=kept, bits_up=bits_up,
+        bits_down=bits_down, problems=prob_names, diagnostics=taps)
 
 
 def run_selection_sweep_sharded(algo_or_chain, problem, x0, rounds: int, *,
@@ -373,56 +348,51 @@ def run_selection_sweep_sharded(algo_or_chain, problem, x0, rounds: int, *,
     pkey = runner_lib.problem_key(ops.stacked)
     lead = (ops.stacked, ops.x0_stack, ops.pol_stack, ops.pst_stack)
 
-    if ops.is_chain:
-        chain = algo_or_chain
-        cell = sweep_lib.make_policy_cell(
-            sweep_lib.make_selection_chain_cell(chain, ops.stacked, rounds,
-                                                "dist-sel", telemetry))
-        fn = _sharded_grid_fn(
-            ("dist-sel-chain", chain._key(), pkey, rounds, telemetry),
-            mesh, cell,
-            cell_in_axes=(None, None, None, None, None, None, None, 0,
-                          None, None, None),
-            replicated_args=(True, True, True, True, False, False, False,
-                             True, True, False, True),
-            donate_argnums=tuple(range(2, 11)))
-        outs = fn(*lead, pidx_c, qidx_c, keys_c, ops.etas_arr,
-                  ops.eta_sched, sel_keys_c, ops.comm0)
-        outs, taps = sweep_lib._split_taps(
-            _unpad_cells(outs, n_cells, lead_shape), telemetry)
-        (x_hat, history, final, kept, bits_up, bits_down, masks,
-         pstate) = outs
-        return sel_sweep.SelectionSweepResult(
-            history=history, final_sub=final, x_hat=x_hat, bits_up=bits_up,
-            bits_down=bits_down, masks=masks, policy_state=pstate,
-            policies=ops.pol_names, problems=ops.prob_names,
-            seeds=ops.seeds, etas=ops.etas, selected_initial=kept,
-            diagnostics=taps)
+    with annotate("repro.sweep.executor"):
+        if ops.is_chain:
+            chain = algo_or_chain
+            cell = sweep_lib.make_policy_cell(
+                sweep_lib.make_selection_chain_cell(
+                    chain, ops.stacked, rounds, "dist-sel", telemetry))
+            fn = _sharded_grid_fn(
+                ("dist-sel-chain", chain._key(), pkey, rounds, telemetry),
+                mesh, cell,
+                cell_in_axes=(None, None, None, None, None, None, None, 0,
+                              None, None, None),
+                replicated_args=(True, True, True, True, False, False,
+                                 False, True, True, False, True),
+                donate_argnums=tuple(range(2, 11)))
+            tail = (ops.eta_sched,)
+        else:
+            cell = sweep_lib.make_policy_cell(
+                sweep_lib.make_selection_algo_cell(
+                    algo_or_chain, ops.stacked, rounds, eval_output,
+                    ops.eta_mode, "dist-sel", telemetry))
+            fn = _sharded_grid_fn(
+                ("dist-sel-algo", algo_or_chain, pkey, rounds, eval_output,
+                 ops.eta_mode, telemetry),
+                mesh, cell,
+                cell_in_axes=(None, None, None, None, None, None, None, 0,
+                              None, None),
+                replicated_args=(True, True, True, True, False, False,
+                                 False, True, False, True),
+                donate_argnums=tuple(range(2, 10)))
+            tail = ()
 
-    algo = algo_or_chain
-    cell = sweep_lib.make_policy_cell(
-        sweep_lib.make_selection_algo_cell(algo, ops.stacked, rounds,
-                                           eval_output, ops.eta_mode,
-                                           "dist-sel", telemetry))
-    fn = _sharded_grid_fn(
-        ("dist-sel-algo", algo, pkey, rounds, eval_output, ops.eta_mode,
-         telemetry),
-        mesh, cell,
-        cell_in_axes=(None, None, None, None, None, None, None, 0, None,
-                      None),
-        replicated_args=(True, True, True, True, False, False, False, True,
-                         False, True),
-        donate_argnums=tuple(range(2, 10)))
-    outs = fn(*lead, pidx_c, qidx_c, keys_c, ops.etas_arr, sel_keys_c,
-              ops.comm0)
+    with annotate("repro.sweep.dispatch"):
+        outs = fn(*lead, pidx_c, qidx_c, keys_c, ops.etas_arr, *tail,
+                  sel_keys_c, ops.comm0)
+
     outs, taps = sweep_lib._split_taps(
         _unpad_cells(outs, n_cells, lead_shape), telemetry)
-    x_hat, history, final, bits_up, bits_down, masks, pstate = outs
+    x_hat, history, final, *rest = outs
+    kept = rest.pop(0) if ops.is_chain else None
+    bits_up, bits_down, masks, pstate = rest
     return sel_sweep.SelectionSweepResult(
         history=history, final_sub=final, x_hat=x_hat, bits_up=bits_up,
         bits_down=bits_down, masks=masks, policy_state=pstate,
         policies=ops.pol_names, problems=ops.prob_names, seeds=ops.seeds,
-        etas=ops.etas, diagnostics=taps)
+        etas=ops.etas, selected_initial=kept, diagnostics=taps)
 
 
 def run_fraction_sweep_sharded(chain, problem, x0, rounds: int, *,

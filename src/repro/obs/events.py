@@ -11,26 +11,17 @@ one ``phase`` event per benchmark phase (``repro.obs.profile.phase``), and
 ``launch.metrics.MetricsLogger`` schema, which is now a shim over this
 recorder). ``python -m repro.obs report`` summarizes a log.
 
-The ONE trace-time artifact in this module is ``TRACE_EVENTS``: a Counter
-the executor bodies bump beside ``runner.TRACE_COUNTS`` when they (re)trace.
-It is the registered obs event sink for traced code — the trace-discipline
-analyzer (R2) whitelists bumps into it exactly like TRACE_COUNTS bumps, and
-``observed_call`` turns its movement into host-side ``compile`` events after
-the fact. No recorder I/O ever happens at trace time.
+Nothing here runs at trace time: ``observed_call`` reads the executors'
+own trace marker, ``runner.TRACE_COUNTS``, before and after a call.
 """
 from __future__ import annotations
 
-import collections
 import contextlib
 import json
 import os
 import time
 from collections import deque
 from typing import Optional
-
-# Trace-time event sink: executor bodies bump this beside TRACE_COUNTS when
-# (re)traced. R2-whitelisted (see repro.analysis.lint.base.TRACE_WHITELIST).
-TRACE_EVENTS: collections.Counter = collections.Counter()
 
 # default event-log path (repo-root relative; uncommitted, see .gitignore)
 DEFAULT_PATH = "obs_events.jsonl"
@@ -146,28 +137,25 @@ def _donate_of(key):
 def observed_call(key, fn, args, kwargs):
     """Run one concrete top-level executor call under the recorder.
 
-    Snapshots ``runner.TRACE_COUNTS`` and ``TRACE_EVENTS`` around the call;
-    when either moved, the call paid a (re)trace and a ``compile`` event is
+    Snapshots ``runner.TRACE_COUNTS`` around the call; when it moved, the
+    call paid a (re)trace and a ``compile`` event is
     emitted with the family, trace tags, wall seconds, and donation tuple
     (plus jaxpr const bytes when the recorder opted in).
     """
     from repro.core import runner
 
     before = dict(runner.TRACE_COUNTS)
-    ev_before = dict(TRACE_EVENTS)
     t0 = time.perf_counter()
     out = fn(*args, **kwargs)
     wall = time.perf_counter() - t0
     deltas = runner.trace_deltas(before)
-    ev_deltas = {k: v - ev_before.get(k, 0) for k, v in TRACE_EVENTS.items()
-                 if v != ev_before.get(k, 0)}
-    if deltas or ev_deltas:
+    if deltas:
         family = key[0] if isinstance(key, tuple) and key else str(key)
         fields = dict(
             family=family,
             cache_key=_key_repr(key),
-            traces=sum(deltas.values()) or sum(ev_deltas.values()),
-            trace_tags=sorted(set(deltas) | set(ev_deltas)),
+            traces=sum(deltas.values()),
+            trace_tags=sorted(deltas),
             compile_s=round(wall, 6),
             donate=_donate_of(key),
         )

@@ -1,13 +1,19 @@
-"""Layer 3: profiled benchmarks — run manifests, ``jax.profiler`` trace
-annotations, and a uniform compile-vs-warm phase capture.
+"""Layer 3: the program's host spans and round-body scopes, run manifests,
+and a uniform compile-vs-warm phase capture.
 
-``benchmarks/run.py --profile`` composes these: every harness runs inside
-``annotate`` scopes (visible in a profiler trace when one is being
-captured), each phase's wall seconds and ``TRACE_COUNTS`` movement land in
-the obs event log as ``phase`` events, and ``write_manifest`` records the
-run environment (backend, devices, XLA flags, config hash) next to every
-``BENCH_*.json`` so benchmark numbers are attributable to a machine state.
-All host-side; nothing here runs in a trace.
+* ``annotate(name, **args)``: a ``jax.profiler.TraceAnnotation`` span on
+  the host (free while no profiler runs). The sweep host path opens
+  ``repro.sweep.*`` spans with it; ``benchmarks/run.py --profile`` wraps
+  its harness phases.
+* ``scope(name)``: a ``jax.named_scope`` over one part of the round body,
+  named from the fixed vocabulary ``SCOPES``. A scope only changes the op
+  metadata (``op_name``) of what it covers, never the computation, so a
+  profiler trace can give every device op its part of the round.
+* ``phase``/``write_manifest``: benchmark phases as obs ``phase`` events and
+  the run environment next to every ``BENCH_*.json``.
+
+Spans and manifests are host-side; scopes run at trace time and leave no
+other trace behind.
 """
 from __future__ import annotations
 
@@ -17,14 +23,15 @@ import json
 import os
 import time
 
+import jax
+from jax.profiler import TraceAnnotation
+
 MANIFEST_PATH = "BENCH_manifest.json"
 
 
 def run_manifest(extra: dict = None) -> dict:
     """The run environment a benchmark number depends on, as a flat dict
     with a stable ``config_hash`` over the sorted contents."""
-    import jax
-
     manifest = {
         "backend": jax.default_backend(),
         "device_count": jax.device_count(),
@@ -49,17 +56,26 @@ def write_manifest(path: str = MANIFEST_PATH, extra: dict = None) -> dict:
     return manifest
 
 
-@contextlib.contextmanager
-def annotate(name: str):
-    """A named ``jax.profiler`` trace annotation (no-op without profiler
-    support) — harness phases show up as labeled spans in captured traces."""
-    try:
-        from jax.profiler import TraceAnnotation
-    except ImportError:  # profiler not available on this build
-        yield
-        return
-    with TraceAnnotation(name):
-        yield
+# the parts of a round a device op can belong to (``scope``); a trace
+# reader gives each op the first of these in its name stack, or none
+SCOPES = ("sample", "local", "downlink", "uplink", "server", "select",
+          "eval", "taps")
+
+
+def annotate(name: str, **args):
+    """A named host span in a captured profiler trace, with ``args`` as
+    its arguments; costs a flag check while no profiler runs."""
+    return TraceAnnotation(name, **args)
+
+
+def scope(name: str):
+    """A ``jax.named_scope`` over one part of the round body: use as
+    ``with scope("local"):`` or as a decorator. ``name`` must be one of
+    ``SCOPES``."""
+    if name not in SCOPES:
+        raise ValueError(f"unknown round-body scope {name!r}; the "
+                         f"vocabulary is {SCOPES}")
+    return jax.named_scope(name)
 
 
 @contextlib.contextmanager

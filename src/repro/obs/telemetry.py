@@ -22,6 +22,7 @@ import dataclasses
 import jax.numpy as jnp
 
 from repro.core import tree_math as tm
+from repro.obs.profile import scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -46,6 +47,7 @@ class Telemetry:
     stage_index: bool = True     # active chain stage id (chain executors)
 
 
+@scope("taps")
 def round_taps(tel: Telemetry, *, problem=None, prev_x=None, new_x=None,
                x_eval=None, comm=None, mask=None, pstate=None,
                stage=None, bits_up=None, bits_down=None) -> dict:

@@ -43,6 +43,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.obs.profile import scope
 from repro.selection.state import (POLICY_IDS, PROBING_POLICIES, PolicyParams,
                                    PolicyState, init_state, make_params)
 
@@ -85,6 +86,7 @@ def probe_bits(params: PolicyParams, num_clients: int):
                      jnp.float32(0.0), jnp.float32(32.0 * num_clients))
 
 
+@scope("sample")
 def round_select(problem, x, pstate: PolicyState, params: PolicyParams, key):
     """One selection step: ``(mask [N] float32, new PolicyState)``.
 

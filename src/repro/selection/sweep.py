@@ -34,6 +34,7 @@ from repro.core import chain as chain_lib
 from repro.core import runner as runner_lib
 from repro.core import sweep as sweep_lib
 from repro.core import tree_math as tm
+from repro.obs.profile import annotate
 from repro.selection.policies import SelectionPolicy
 
 
@@ -106,7 +107,9 @@ def selection_grid_operands(algo_or_chain, problem, x0, rounds: int, *,
                             eval_output: bool = True):
     """Host-side operand derivation SHARED by the vmapped and sharded
     engines — both consume these exact per-cell values, which is what makes
-    ``mesh=`` bitwise identical."""
+    ``mesh=`` bitwise identical. Opens the ``repro.sweep.operands`` span,
+    then ``repro.sweep.masks`` around the per-cell selection keys (what
+    stands in for the mask schedule)."""
     from repro.comm import config as comm_cfg
 
     is_chain = isinstance(algo_or_chain, chain_lib.Chain)
@@ -130,44 +133,51 @@ def selection_grid_operands(algo_or_chain, problem, x0, rounds: int, *,
     for st in stages:
         comm_cfg.reject_algo_participation(getattr(st, "s", 0), st.name)
 
-    if problems is None:
-        spec = runner_lib.as_spec(problem)
-        if spec is None:
-            raise TypeError(
-                "run_selection_sweep needs spec-backed problems (the "
-                "policy/problem stacks are gathered per cell)")
-        from repro.data import spec as spec_lib
+    with annotate("repro.sweep.operands"):
+        if problems is None:
+            spec = runner_lib.as_spec(problem)
+            if spec is None:
+                raise TypeError(
+                    "run_selection_sweep needs spec-backed problems (the "
+                    "policy/problem stacks are gathered per cell)")
+            from repro.data import spec as spec_lib
 
-        stacked, prob_names = spec_lib.stack_specs([spec]), (spec.name,)
-    else:
-        stacked, prob_names = sweep_lib._as_stacked_specs(problems)
-    n_probs = len(prob_names)
-    n_seeds = len(seeds)
-    n_pols = len(policies)
-    n_clients = int(stacked.num_clients)
-    x0_stack = sweep_lib._normalize_x0_stack(x0, stacked, n_probs)
+            stacked, prob_names = spec_lib.stack_specs([spec]), (spec.name,)
+        else:
+            stacked, prob_names = sweep_lib._as_stacked_specs(problems)
+        n_probs = len(prob_names)
+        n_seeds = len(seeds)
+        n_pols = len(policies)
+        n_clients = int(stacked.num_clients)
+        x0_stack = sweep_lib._normalize_x0_stack(x0, stacked, n_probs)
 
-    pol_stack = jax.tree.map(
-        lambda *xs: jnp.stack(xs), *[q.params(n_clients) for q in policies])
-    pst_stack = jax.tree.map(
-        lambda *xs: jnp.stack(xs),
-        *[q.init_state(n_clients) for q in policies])
-    qidx, pidx = sweep_lib.policy_index_operands(n_pols, n_probs, n_seeds)
+        pol_stack = jax.tree.map(
+            lambda *xs: jnp.stack(xs),
+            *[q.params(n_clients) for q in policies])
+        pst_stack = jax.tree.map(
+            lambda *xs: jnp.stack(xs),
+            *[q.init_state(n_clients) for q in policies])
+        qidx, pidx = sweep_lib.policy_index_operands(n_pols, n_probs,
+                                                     n_seeds)
 
-    keys = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
-    keys_c = jnp.tile(keys, (n_pols * n_probs, 1))
-    n_sched = (algo_or_chain.schedule_len(rounds) if is_chain else rounds)
-    # selection keys: policy-INDEPENDENT fold p·S + s, so every policy at a
-    # given (problem, seed) cell consumes the same randomness (paired
-    # comparisons) and the uniform policy replays the comm mask-schedule
-    # fold convention exactly
-    sel_keys_c = jnp.stack([
-        q.sel_keys(n_sched, fold=p * n_seeds + s)
-        for q in policies for p in range(n_probs) for s in range(n_seeds)])
+        keys = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
+        keys_c = jnp.tile(keys, (n_pols * n_probs, 1))
+        etas_arr = jnp.asarray(etas, jnp.float32)
+        eta_sched = (algo_or_chain.eta_schedule(rounds) if is_chain
+                     else None)
+        comm0 = comm.init_state(n_clients, tm.tree_index(x0_stack, 0))
 
-    etas_arr = jnp.asarray(etas, jnp.float32)
-    eta_sched = (algo_or_chain.eta_schedule(rounds) if is_chain else None)
-    comm0 = comm.init_state(n_clients, tm.tree_index(x0_stack, 0))
+    with annotate("repro.sweep.masks"):
+        n_sched = (algo_or_chain.schedule_len(rounds) if is_chain
+                   else rounds)
+        # selection keys: policy-INDEPENDENT fold p·S + s, so every policy
+        # at a given (problem, seed) cell consumes the same randomness
+        # (paired comparisons) and the uniform policy replays the comm
+        # mask-schedule fold convention exactly
+        sel_keys_c = jnp.stack([
+            q.sel_keys(n_sched, fold=p * n_seeds + s)
+            for q in policies for p in range(n_probs)
+            for s in range(n_seeds)])
 
     return types.SimpleNamespace(
         is_chain=is_chain, eta_mode=eta_mode, policies=policies,
@@ -229,31 +239,25 @@ def _run_selection_sweep(algo_or_chain, problem, x0, rounds: int, *,
         etas=etas, eta_mode=eta_mode, comm=comm, problems=problems,
         eval_output=eval_output)
 
-    if ops.is_chain:
-        fn = sweep_lib._sweep_fn_selection_chain(
-            algo_or_chain, ops.stacked, rounds, telemetry)
-        outs, taps = sweep_lib._split_taps(_grid_shape(ops, fn(
-            ops.stacked, ops.x0_stack, ops.pol_stack, ops.pst_stack,
-            ops.pidx, ops.qidx, ops.keys_c, ops.etas_arr, ops.eta_sched,
-            ops.sel_keys_c, ops.comm0)), telemetry)
-        (x_hat, history, final, kept, bits_up, bits_down, masks,
-         pstate) = outs
-        return SelectionSweepResult(
-            history=history, final_sub=final, x_hat=x_hat, bits_up=bits_up,
-            bits_down=bits_down, masks=masks, policy_state=pstate,
-            policies=ops.pol_names, problems=ops.prob_names, seeds=ops.seeds,
-            etas=ops.etas, selected_initial=kept, diagnostics=taps)
-
-    fn = sweep_lib._sweep_fn_selection_algo(
-        algo_or_chain, ops.stacked, rounds, eval_output, ops.eta_mode,
-        telemetry)
-    outs, taps = sweep_lib._split_taps(_grid_shape(
-        ops, fn(ops.stacked, ops.x0_stack, ops.pol_stack, ops.pst_stack,
-                ops.pidx, ops.qidx, ops.keys_c, ops.etas_arr, ops.sel_keys_c,
-                ops.comm0)), telemetry)
-    x_hat, history, final, bits_up, bits_down, masks, pstate = outs
+    with annotate("repro.sweep.executor"):
+        if ops.is_chain:
+            fn = sweep_lib._sweep_fn_selection_chain(
+                algo_or_chain, ops.stacked, rounds, telemetry)
+        else:
+            fn = sweep_lib._sweep_fn_selection_algo(
+                algo_or_chain, ops.stacked, rounds, eval_output,
+                ops.eta_mode, telemetry)
+    eta_sched = (ops.eta_sched,) if ops.is_chain else ()
+    with annotate("repro.sweep.dispatch"):
+        outs = fn(ops.stacked, ops.x0_stack, ops.pol_stack, ops.pst_stack,
+                  ops.pidx, ops.qidx, ops.keys_c, ops.etas_arr, *eta_sched,
+                  ops.sel_keys_c, ops.comm0)
+    outs, taps = sweep_lib._split_taps(_grid_shape(ops, outs), telemetry)
+    x_hat, history, final, *rest = outs
+    kept = rest.pop(0) if ops.is_chain else None
+    bits_up, bits_down, masks, pstate = rest
     return SelectionSweepResult(
         history=history, final_sub=final, x_hat=x_hat, bits_up=bits_up,
         bits_down=bits_down, masks=masks, policy_state=pstate,
         policies=ops.pol_names, problems=ops.prob_names, seeds=ops.seeds,
-        etas=ops.etas, diagnostics=taps)
+        etas=ops.etas, selected_initial=kept, diagnostics=taps)

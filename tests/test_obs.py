@@ -10,8 +10,14 @@
     ``policy_t`` counts rounds, EF-off residual norms are exactly 0.0;
 (d) the event recorder writes the JSONL schema, closes under the context
     manager, turns executor traces into ``compile`` events, and the
-    ``python -m repro.obs report`` CLI summarizes a log.
+    ``python -m repro.obs report`` CLI summarizes a log;
+(e) the round-body scopes reach a compiled executor's op metadata, and a
+    grid call opens its host spans, nested and in order, in a profiler
+    trace.
 """
+import glob
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -244,3 +250,63 @@ def test_metrics_logger_is_obs_schema_and_report_cli(tmp_path, capsys):
     out = capsys.readouterr().out
     assert "metrics: 4 record(s)" in out and "loss" in out
     assert obs_main(["report", str(tmp_path / "missing.jsonl")]) == 2
+
+
+# --------------- (e) program spans and round-body scopes --------------------
+
+def _scopes_in(text):
+    """Round-body scopes named in compiled HLO text's op_name metadata."""
+    from repro.obs import SCOPES
+
+    return {s for s in SCOPES
+            if re.search(rf'op_name="[^"]*[/(]{s}[/)"]', text)}
+
+
+def test_chain_comm_executor_carries_round_body_scopes(spec):
+    from repro.comm import CommPlan, Leg
+
+    chain = chain_lib.fedchain(
+        A.FedAvg(eta=0.1, k=4, local_steps=2, inner_batch=2),
+        A.SGD(eta=0.1, k=4, mu_avg=0.1), local_fraction=0.5)
+    plan = CommPlan(uplink=Leg("qsgd", qsgd_bits=4, error_feedback=True),
+                    downlink=Leg("qsgd", qsgd_bits=4), participation=0.5)
+    states0 = chain.init_states(spec, spec.x0)
+    masks = plan.round_masks(chain.schedule_len(R), spec.num_clients)
+    fn = jax.jit(chain.executor_body(spec, R, comm=True))
+    text = fn.lower(spec, spec.x0, states0, jax.random.PRNGKey(0),
+                    chain.eta_schedule(R), masks,
+                    plan.init_state(spec.num_clients, spec.x0)
+                    ).compile().as_text()
+    assert {"sample", "local", "downlink", "uplink", "select",
+            "eval"} <= _scopes_in(text)
+
+
+def test_sweep_run_opens_nested_host_spans_in_order(spec, algo, comm_cfg,
+                                                    tmp_path):
+    from jax.profiler import ProfileData
+
+    sweep.run_sweep(algo, spec, spec.x0, R, seeds=SEEDS, etas=ETAS,
+                    comm=comm_cfg)  # warm: the traced calls compile nothing
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(2):
+            jax.block_until_ready(sweep.run_sweep(
+                algo, spec, spec.x0, R, seeds=SEEDS, etas=ETAS,
+                comm=comm_cfg).history)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = sorted(
+        (e.start_ns, e.start_ns + e.duration_ns, e.name, dict(e.stats))
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines for e in line.events
+        if e.name.startswith("repro.sweep."))
+    runs = [s for s in spans if s[2] == "repro.sweep.run"]
+    assert len(runs) == 2
+    first, second = (r[3]["call"] for r in runs)
+    assert first >= 1 and second == first + 1
+    for lo, hi, _, _ in runs:
+        inner = [s[2] for s in spans
+                 if lo <= s[0] and s[1] <= hi and s[2] != "repro.sweep.run"]
+        assert inner == ["repro.sweep." + c for c in
+                         ("operands", "masks", "executor", "dispatch")]
