@@ -246,7 +246,9 @@ def _logreg_batch(spec, i, key):
     d = spec.data
     n_per = d["features"].shape[1]
     idx = jax.random.randint(key, (spec.batch,), 0, n_per)
-    return d["features"][i][idx], d["labels"][i][idx]
+    # one (client, row) gather: ``features[i][idx]`` under a vmap over
+    # clients gathers each client's whole shard before picking the rows
+    return d["features"][i, idx], d["labels"][i, idx]
 
 
 def _logreg_grad(spec, w, i, key):
@@ -306,7 +308,7 @@ def _vision_batch(spec, i, key):
     d = spec.data
     n_per = d["features"].shape[1]
     idx = jax.random.randint(key, (spec.batch,), 0, n_per)
-    return d["features"][i][idx], d["labels"][i][idx]
+    return d["features"][i, idx], d["labels"][i, idx]
 
 
 def _vision_grad(spec, params, i, key):

@@ -10,12 +10,16 @@
     keys) and the executor cache holds no problem references;
 (d) multi-method stacking matches per-method runs through one compile;
 (e) logreg F*/x* come from the high-precision Newton solve and unknown-F*
-    suboptimality is an explicit (warning) fallback, not a silent 0.
+    suboptimality is an explicit (warning) fallback, not a silent 0;
+(f) the logreg and vision oracles read a query's rows in one gather under
+    a cells × clients × queries vmap, never a whole client shard, and the
+    rows are exactly ``features[i][idx]``.
 """
 import gc
 import weakref
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -333,3 +337,110 @@ def test_spec_constants_are_leaves():
                                np.asarray(ZETAS), rtol=1e-6)
     assert stacked.x0.shape == (len(ZETAS), 12)
     assert spec_lib.spec_count(stacked) == len(ZETAS)
+
+
+# ---------------------------------------------------------------------------
+# (f) minibatch oracles gather the sampled rows, not whole shards
+# ---------------------------------------------------------------------------
+
+# cells × clients × queries; rows per client (_N_PER) matches no other size
+_CELLS, _CLIENTS, _QUERIES, _N_PER, _D = 2, 5, 4, 40, 12
+
+
+def _oracle_logreg():
+    rng = np.random.default_rng(0)
+    spec = spec_lib.logreg_spec(
+        jax.random.PRNGKey(0),
+        features=rng.normal(size=(_CLIENTS, _N_PER, _D)).astype(np.float32),
+        labels=(rng.random((_CLIENTS, _N_PER)) > 0.5).astype(np.float32),
+        l2=0.1, oracle_batch_frac=3 / _N_PER, solve_f_star=False)
+    return spec, spec_lib._logreg_batch, spec_lib._logreg_loss_on
+
+
+def _oracle_vision():
+    from repro.data import vision_problem
+
+    rng = np.random.default_rng(0)
+    spec = vision_problem.vision_spec_from_shards(
+        jax.random.PRNGKey(0),
+        rng.normal(size=(_CLIENTS, _N_PER, _D)).astype(np.float32),
+        rng.integers(0, 3, size=(_CLIENTS, _N_PER)),
+        num_classes=3, hidden=7, batch=3)
+    return spec, spec_lib._vision_batch, spec_lib._vision_loss_on
+
+
+def _nested(fn, query_axes=(None, None, 0)):
+    """``fn(x, i, key)`` over cells × clients × queries: x per cell, client
+    ids [cells, clients], keys [cells, clients, queries]."""
+    per_client = jax.vmap(fn, in_axes=query_axes)
+    per_cell = jax.vmap(per_client, in_axes=(None, 0, 0))
+    return jax.vmap(per_cell)
+
+
+def _nested_operands(spec):
+    xs = jax.tree.map(lambda v: jnp.stack([v, v + 0.1]), spec.x0)
+    ids = jnp.stack([jnp.arange(_CLIENTS), jnp.arange(_CLIENTS)[::-1]])
+    keys = jax.random.split(jax.random.PRNGKey(3),
+                            _CELLS * _CLIENTS * _QUERIES)
+    return xs, ids, keys.reshape(_CELLS, _CLIENTS, _QUERIES, -1)
+
+
+def _gathers(closed):
+    for eqn in closed.jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jex_core.ClosedJaxpr):
+                    yield from _gathers(sub)
+
+
+@pytest.mark.parametrize("build", [_oracle_logreg, _oracle_vision],
+                         ids=["logreg", "vision"])
+def test_oracle_gathers_rows_not_shards(build):
+    """A query reads its rows by (client, row) in one gather: no gather in
+    the nested-vmap grad oracle slices a client's whole shard."""
+    spec, _, _ = build()
+    xs, ids, keys = _nested_operands(spec)
+    closed = jax.make_jaxpr(
+        lambda s, x, i, k: _nested(s.grad_oracle)(x, i, k))(
+            spec, xs, ids, keys)
+    gathers = list(_gathers(closed))
+    assert gathers
+    for eqn in gathers:
+        assert _N_PER not in tuple(eqn.params["slice_sizes"]), eqn
+
+
+@pytest.mark.parametrize("build", [_oracle_logreg, _oracle_vision],
+                         ids=["logreg", "vision"])
+def test_oracle_rows_match_numpy_gather(build):
+    """The batch is exactly ``features[i][idx]`` for the oracle's own
+    ``idx`` (the rows the chip benchmark's reference replays), and the
+    gradient is the loss's gradient on those rows, bit for bit."""
+    spec, batch_fn, loss_on = build()
+    xs, ids, keys = _nested_operands(spec)
+    X, y = jax.jit(_nested(lambda x, i, k: batch_fn(spec, i, k)))(
+        xs, ids, keys)
+    grads = jax.jit(_nested(spec.grad_oracle))(xs, ids, keys)
+
+    feats = np.asarray(spec.data["features"])
+    labels = np.asarray(spec.data["labels"])
+    idx = np.asarray(jax.vmap(
+        lambda k: jax.random.randint(k, (spec.batch,), 0, _N_PER))(
+            keys.reshape(-1, keys.shape[-1])))
+    idx = idx.reshape(_CELLS, _CLIENTS, _QUERIES, spec.batch)
+    X_ref = np.empty(X.shape, feats.dtype)
+    y_ref = np.empty(y.shape, labels.dtype)
+    for c, m, q in np.ndindex(_CELLS, _CLIENTS, _QUERIES):
+        i = int(ids[c, m])
+        X_ref[c, m, q] = feats[i][idx[c, m, q]]
+        y_ref[c, m, q] = labels[i][idx[c, m, q]]
+    np.testing.assert_array_equal(np.asarray(X), X_ref)
+    np.testing.assert_array_equal(np.asarray(y), y_ref)
+
+    grad_on = jax.grad(loss_on, argnums=1)
+    grads_ref = jax.jit(_nested(
+        lambda x, Xq, yq: grad_on(spec, x, Xq, yq), query_axes=(None, 0, 0)))(
+            xs, jnp.asarray(X_ref), jnp.asarray(y_ref))
+    for g, g_ref in zip(jax.tree.leaves(grads), jax.tree.leaves(grads_ref)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(g_ref))
