@@ -7,14 +7,15 @@ import jax.numpy as jnp
 from chipbench.reference import logreg as model
 
 
-def build_problem(config: dict, features, classes, x0):
+def build_problem(config: dict, data: dict, x0):
     """The program's problem for this configuration (no host F* solve:
     timing does not need F*, so ``history`` holds the raw loss)."""
     from repro.data import spec as spec_lib
 
     del x0  # the paper starts logistic regression at zero, as the spec does
     return spec_lib.logreg_spec(
-        None, features=features, labels=model.labels(classes),
+        None, features=data["features"],
+        labels=model.labels(data["classes"]),
         l2=float(config["l2"]),
         oracle_batch_frac=config["oracle_batch"] / config["per_client"],
         solve_f_star=False, name=config["name"])

@@ -7,7 +7,7 @@ import jax.numpy as jnp
 from chipbench.reference import mlp as model
 
 
-def build_problem(config: dict, features, classes, x0):
+def build_problem(config: dict, data: dict, x0):
     """The program's problem: ``_vision_apply`` takes its depth from the
     params pytree, so any number of hidden layers runs unchanged."""
     from repro.data import spec as spec_lib
@@ -19,7 +19,8 @@ def build_problem(config: dict, features, classes, x0):
         family=spec_lib.FAMILY_VISION, num_clients=config["num_clients"],
         dim=int(config["params"]), batch=int(config["batch"]),
         arch=tuple(config["arch"]), name=config["name"],
-        data=dict(features=features, labels=model.labels(classes)),
+        data=dict(features=data["features"],
+                  labels=model.labels(data["classes"])),
         consts=consts, x0=x0,
         x_star={k: jnp.zeros_like(v) for k, v in x0.items()})
 

@@ -4,9 +4,15 @@ Everything that belongs to one configuration, traffic mix, metric or
 kernel lives in a file of its own, found by the name ``BENCHMARK.json``
 gives it:
 
-* ``configs/<config>.json``: sizes, method, precision, sources;
-* ``families/<family>.py``: the program's problem for that family and the
-  model's operation counts; ``reference/<family>.py``: its plain loss;
+* ``configs/<config>.json``: sizes, method, precision, sources, and the
+  name of the client population (``population``, ``mnist-shards`` where
+  the file names none);
+* ``populations/<population>.py``: ``make(config, key)``, the per-client
+  arrays from the seed, a dict of arrays with leading axes [N, n];
+* ``families/<family>.py``: the program's problem for that family, built
+  from that dict, the model's operation counts and, optionally, the Pallas
+  kernels its own layers call (``kernel_calls``); ``reference/<family>.py``:
+  its plain loss, parameters and the arrays it takes from the dict;
 * ``traffic/<mix>.json``: participation, comm legs, selection policy, grid
   shape, rounds, chips, how many cells the check replays;
 * ``limits/<cell>.json``: the limit of every number the check compares;
@@ -37,6 +43,7 @@ BENCHMARK = os.path.join(ROOT, "BENCHMARK.json")
 # ``vmap`` helpers (the comm mask schedule) on every call, without compiling.
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+DEFAULT_POPULATION = "mnist-shards"
 
 
 # -- lookup by name ----------------------------------------------------------
@@ -135,6 +142,8 @@ class Cell:
         self.traffic = (load_json("traffic", self.workload["traffic"])
                         if traffic is None else traffic)
         self.limits = load_json("limits", name) if limits is None else limits
+        self.population = load_module(
+            "populations", config.get("population", DEFAULT_POPULATION))
         self.family = load_module("families", config["family"])
         self.model = load_module("reference", config["family"])
         self.chips = int(self.workload["chips"])
@@ -151,7 +160,6 @@ class Cell:
         import jax
         import jax.numpy as jnp
 
-        from chipbench.population import population
         from repro.core import algorithms as A, chain
 
         # the configuration's precision, for everything the program traces
@@ -159,10 +167,10 @@ class Cell:
                           self.config["matmul_precision"])
         key = jax.random.PRNGKey(data_seed(seed))
         k_pop, k_init = jax.random.split(key)
-        self.features, self.classes = population(self.config, k_pop)
+        self.data = self.population.make(self.config, k_pop)
         self.x0 = self.model.init(self.config, k_init, jnp.float32)
-        self.problem = self.family.build_problem(
-            self.config, self.features, self.classes, self.x0)
+        self.problem = self.family.build_problem(self.config, self.data,
+                                                 self.x0)
         self.x0 = self.problem.x0
         m = self.config["method"]
         loc, glob = m["local"], m["global"]
@@ -247,8 +255,7 @@ class Cell:
 
         from chipbench.reference.fedchain import Reference
 
-        return Reference(self.model, self.config, self.traffic,
-                         self.features, self.classes,
+        return Reference(self.model, self.config, self.traffic, self.data,
                          dtype=jnp.float32 if dtype is None else dtype,
                          precision=precision, fault=fault)
 

@@ -11,22 +11,31 @@ round paths on a TPU (where every kernel runs under Mosaic):
 * without it FedAvg averages the reconstructions and SGD its gradients
   through ``weighted_mean_over_clients`` per leaf (SGD on a flat vector
   goes through ``chain_aggregate`` instead);
-* the selection row runs no kernel.
+* the selection row runs no kernel;
+* the kernels a model's own layers call are the family's to declare, in
+  an optional ``kernel_calls(config, traffic, cells)`` of
+  ``families/<family>.py`` returning tuples as ``per_call`` does.
 
 Under ``vmap`` one kernel call covers every cell on the device.
 """
 from __future__ import annotations
 
+import math
+
+from chipbench.harness import load_module
+
 
 def leaf_widths(config: dict) -> list:
-    if "arch" in config:
-        arch = config["arch"]
-        widths = {}
-        for i, (a, b) in enumerate(zip(arch[:-1], arch[1:])):
-            widths[f"w{i}"] = a * b
-            widths[f"b{i}"] = b
-        return [widths[k] for k in sorted(widths)]
-    return [config["dim"]]
+    """Sizes of the parameter leaves, in ``jax.tree`` flattening order (the
+    order in which the engine compresses them), from the shapes of the
+    reference's ``init``."""
+    import jax
+    import jax.numpy as jnp
+
+    model = load_module("reference", config["family"])
+    shapes = jax.eval_shape(lambda k: model.init(config, k, jnp.float32),
+                            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    return [math.prod(leaf.shape) for leaf in jax.tree.leaves(shapes)]
 
 
 def per_call(config: dict, traffic: dict, cells: int) -> list:
@@ -52,4 +61,8 @@ def per_call(config: dict, traffic: dict, cells: int) -> list:
             out.append(("weighted_mean_over_clients", cells, n, w, b1))
             out.append(("chain_aggregate" if flat
                         else "weighted_mean_over_clients", cells, n, w, b2))
+    declared = getattr(load_module("families", config["family"]),
+                       "kernel_calls", None)
+    if declared is not None:
+        out.extend(declared(config, traffic, cells))
     return out

@@ -1,4 +1,8 @@
-"""The benchmark's own client population, made on the device from a seed.
+"""The ``mnist-shards`` client population, made on the device from a seed.
+
+A configuration names its population with a ``population`` key; one that
+names none runs on this one. ``make(config, key)`` returns
+``{"features": [N, n, side^2] float32, "classes": [N, n] int32}``.
 
 This is the benchmark's data, not the program's: a later change to the
 program's data modules cannot change what the cells run on. It follows the
@@ -59,11 +63,12 @@ def make_population(key, *, num_clients: int, per_client: int, side: int,
     return pixels[idx], labels[idx]
 
 
-def population(config: dict, key):
+def make(config: dict, key) -> dict:
     """The population a configuration file describes."""
-    return make_population(
+    features, classes = make_population(
         key, num_clients=config["num_clients"],
         per_client=config["per_client"], side=config["side"],
         classes=config["classes"],
         shards_per_client=config["shards_per_client"],
         noise=float(config["noise"]))
+    return {"features": features, "classes": classes}

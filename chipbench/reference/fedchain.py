@@ -7,6 +7,19 @@ test. What makes it comparable with the program cell for cell is that it
 draws the same random numbers: a cell's randomness is a function of its
 seed, and the derivation below is the one the engine documents.
 
+It holds one client at a time. Every per-client computation (FedAvg's
+local steps, SGD's client gradients, the selection values, the UCB probe,
+the global loss) is a sequential map over the clients, and a round's uplink
+is one scan over the clients in the round's order: each client's row is
+made, compressed and added to the server's sum before the next client's.
+So at a model's width the reference keeps one client's parameters,
+gradients and activations, besides the per-client feedback residuals that
+the algorithm itself keeps. The queries within one client stay vectorised.
+
+The data comes from the model module: ``model.arrays(data, dtype)`` turns
+the population's dict into (inputs, targets), each [N, n, ...]; integer
+arrays such as token ids stay integers.
+
 Per cell (seed s, stepsize multiplier m, mask seed, selection seed, fold f):
 
 * Keys. key = PRNGKey(s); four stage keys = split(key, 4). Stage 1 (FedAvg)
@@ -22,8 +35,9 @@ Per cell (seed s, stepsize multiplier m, mask seed, selection seed, fold f):
   c = fold_in(k, 0x636D) and the downlink stream fold_in(c, 2).
 * Client order. Every round enumerates all N clients in a Fisher-Yates
   order: for i < N, j_i = randint(split(k_sample, N)[i], i, N), swap i, j_i.
-* QSGD with b bits on rows v [S, d] and key k: u = uniform(k, (S, d)),
-  L = 2^b - 1, ||v|| per row, q = floor(|v|/||v|| L) + [u < frac], out =
+* QSGD with b bits on rows v [S, d] and key k: u = uniform(k, (S, d))
+  (row i drawn alone, bitwise the same: ``uniform_row``), L = 2^b - 1,
+  ||v|| per row, q = floor(|v|/||v|| L) + [u < frac], out =
   sign(v) ||v|| q / L. A parameter pytree compresses leaf by leaf in
   flattening order, leaf i with split(k, n_leaves)[i] (one leaf: k itself).
 * Downlink (server error feedback): delta = x - ref + res, c = C(delta),
@@ -33,8 +47,9 @@ Per cell (seed s, stepsize multiplier m, mask seed, selection seed, fold f):
   ``inner_batch`` minibatch gradients (step keys split(key_i, steps), query
   keys split(step key, inner_batch)); it uplinks y_i - x_s. With uplink
   error feedback: d_i = y_i - x_s + e_i, c_i = C(d_i), x = x_s + sum over
-  participants c_i / S, participants keep e_i = d_i - c_i. Without: x =
-  mean over participants of (x_s + c_i).
+  participants c_i / S (summed in the round's client order), participants
+  keep e_i = d_i - c_i. Without: x = mean over participants of
+  (x_s + c_i).
 * SGD round: gradients at the downlink reconstruction, K queries a client
   (keys split(k_work, N K) in client-major order), uplinked as g_i (+ e_i
   with feedback); x = x - eta * mean over participants of C(.). The output
@@ -59,10 +74,10 @@ Per cell (seed s, stepsize multiplier m, mask seed, selection seed, fold f):
   downlink, 2 * 32 N up and 2 * 32 D N down on the selection row, and
   32 N up for each UCB probe.
 
-``dtype`` sets the precision of every array and ``precision`` that of the
-matrix products: float32 at ``highest`` for the reference; the control
-drops one step (``high``, three bf16 passes), and bfloat16 arrays are a
-further reading.
+``dtype`` sets the precision of every float array and ``precision`` that
+of the matrix products: float32 at ``highest`` for the reference; the
+control drops one step (``high``, three bf16 passes), and bfloat16 arrays
+are a further reading.
 """
 from __future__ import annotations
 
@@ -71,6 +86,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.random import threefry2x32_p
 
 COMM_TAG = 0x636D
 DOWN_TAG = 2
@@ -83,6 +99,30 @@ def leaves(tree):
 
 def _tree_map(f, *trees):
     return jax.tree.map(f, *trees)
+
+
+def uniform_row(key, shape, row):
+    """Row ``row`` of ``jax.random.uniform(key, shape)`` (float32, ``shape``
+    = (rows, width)), drawn without the other rows.
+
+    JAX's partitionable threefry (``jax_threefry_partitionable``, on by
+    default) draws element (i, j) from the 64-bit counter i * width + j,
+    split into a high and a low 32-bit word; its 32 bits are the two
+    outputs' xor, and the float is 1.0's exponent over the top 23 bits,
+    minus 1.0."""
+    rows, width = shape
+    if not jax.config.jax_threefry_partitionable:
+        raise NotImplementedError("uniform_row follows the partitionable "
+                                  "threefry")
+    if rows * width >= 2 ** 32:
+        raise NotImplementedError("counters of 2**32 and more need a high "
+                                  "word")
+    lo = (jnp.asarray(row).astype(jnp.uint32) * jnp.uint32(width)
+          + jnp.arange(width, dtype=jnp.uint32))
+    b1, b2 = threefry2x32_p.bind(key[0], key[1], jnp.zeros_like(lo), lo)
+    bits = jax.lax.shift_right_logical(b1 ^ b2, jnp.uint32(32 - 23))
+    bits = bits | jnp.uint32(0x3F800000)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32) - jnp.float32(1.0)
 
 
 @dataclasses.dataclass
@@ -99,8 +139,8 @@ class CellResult:
 class Reference:
     """Runs cells of one configuration under one traffic mix."""
 
-    def __init__(self, model, config: dict, traffic: dict, features,
-                 classes, *, dtype=jnp.float32, precision: str = "highest",
+    def __init__(self, model, config: dict, traffic: dict, data: dict, *,
+                 dtype=jnp.float32, precision: str = "highest",
                  fault: str | None = None):
         self.model = model
         self.cfg = config
@@ -108,8 +148,7 @@ class Reference:
         self.dtype = jnp.dtype(dtype)
         self.precision = precision
         self.fault = fault
-        self.set_population(features, classes)
-        self.n_clients, self.n_per = features.shape[:2]
+        self.set_population(data)
         self.batch = model.batch(config)
         self.l2 = float(config["l2"])
         m = config["method"]
@@ -124,14 +163,12 @@ class Reference:
         self.policy = traffic.get("policy")
         self._jit()
 
-    def set_population(self, features, classes):
+    def set_population(self, data: dict):
         """The data every jitted step takes as an argument (never as a
-        constant, so a new population of the same shape compiles nothing)."""
-        X = features.astype(self.dtype)
-        Y = self.model.labels(classes)
-        if Y.dtype != jnp.int32:
-            Y = Y.astype(self.dtype)
-        self.data = (X, Y)
+        constant, so a new population of the same shape compiles nothing):
+        the model's (inputs, targets), each [N, n, ...]."""
+        self.data = tuple(self.model.arrays(data, self.dtype))
+        self.n_clients, self.n_per = self.data[0].shape[:2]
 
     # -- building blocks -------------------------------------------------
 
@@ -151,7 +188,7 @@ class Reference:
         return self._loss(x, X, y)
 
     def _global_loss(self, data, x):
-        per = jax.vmap(lambda X, y: self._loss(x, X, y))(*data)
+        per = jax.lax.map(lambda d: self._loss(x, *d), data)
         return jnp.mean(per)
 
     def _order(self, key):
@@ -167,8 +204,8 @@ class Reference:
 
         return jax.lax.fori_loop(0, n, swap, jnp.arange(n, dtype=jnp.int32))
 
-    def _qsgd_rows(self, v, key, bits):
-        u = jax.random.uniform(key, v.shape, jnp.float32).astype(v.dtype)
+    def _qsgd_rows(self, v, u, bits):
+        u = u.astype(v.dtype)
         norm = jnp.sqrt(jnp.sum(v * v, axis=1, keepdims=True))
         norm = jnp.maximum(norm, jnp.asarray(1e-30, v.dtype))
         levels = jnp.asarray(2.0 ** bits - 1.0, v.dtype)
@@ -177,8 +214,9 @@ class Reference:
         q = lo + (u < scaled - lo).astype(v.dtype)
         return jnp.sign(v) * norm * (q / levels)
 
-    def _compress(self, tree, key, leg):
-        """Leaf-wise compression of per-client rows (leaves [S, ...])."""
+    def _compress_row(self, tree, key, leg, rows, row):
+        """Leaf-wise compression of row ``row`` of a [rows, ...] payload,
+        given that row alone: the same numbers as compressing all rows."""
         if leg["compressor"] == "identity":
             return tree
         if leg["compressor"] != "qsgd":
@@ -187,36 +225,57 @@ class Reference:
         flat, treedef = jax.tree.flatten(tree)
         keys = [key] if len(flat) == 1 else list(
             jax.random.split(key, len(flat)))
-        out = [self._qsgd_rows(l.reshape(l.shape[0], -1), k,
-                               leg["qsgd_bits"]).reshape(l.shape)
-               for l, k in zip(flat, keys)]
+        out = []
+        for l, k in zip(flat, keys):
+            u = uniform_row(k, (rows, l.size), row)
+            out.append(self._qsgd_rows(l.reshape(1, -1), u[None],
+                                       leg["qsgd_bits"]).reshape(l.shape))
         return jax.tree.unflatten(treedef, out)
 
     def _downlink(self, x, ref, res, key):
         if self.down["compressor"] == "identity":
             return x, x, _tree_map(jnp.zeros_like, x)
         delta = _tree_map(lambda a, r, e: a - r + e, x, ref, res)
-        c = self._compress(_tree_map(lambda l: l[None], delta), key,
-                           self.down)
-        c = _tree_map(lambda l: l[0], c)
+        c = self._compress_row(delta, key, self.down, 1, 0)
         recon = _tree_map(jnp.add, ref, c)
         return recon, recon, _tree_map(jnp.subtract, delta, c)
 
-    def _aggregate(self, x_base, rows, mask_rows, eta):
-        """x_base - eta * mean over participants of rows (leaves [N, ...])."""
-        m = mask_rows.astype(self.dtype)
-        total = jnp.maximum(jnp.sum(m), 1.0)
+    def _uplink(self, x_base, payload, order, mask, ures, key, eta):
+        """x_base - eta * mean over participants of the compressed payloads,
+        one client at a time in the round's order; with uplink error
+        feedback each payload carries its client's residual, and
+        participants keep the new one. ``payload(cid, pos)`` makes the
+        payload of client ``cid``, at position ``pos`` of the order.
+        Returns (x, residuals)."""
+        n = self.n_clients
+        m = mask[order]
+        w = m.astype(self.dtype)
         if self.fault == "half_clients":
             # planted fault: only the first half of the rows enter the mean
-            half = self.n_clients // 2
-            m = m * (jnp.arange(self.n_clients) < half).astype(self.dtype)
-            total = jnp.maximum(jnp.sum(m), 1.0)
+            w = w * (jnp.arange(n) < n // 2).astype(self.dtype)
+        total = jnp.maximum(jnp.sum(w), 1.0)
+        ef = self.up.get("error_feedback", False)
 
-        def leaf(xb, r):
-            w = m.reshape((-1,) + (1,) * (r.ndim - 1))
-            return xb - eta * jnp.sum(w * r, axis=0) / total
+        def client(carry, pos):
+            acc, ures = carry
+            cid = order[pos]
+            p = payload(cid, pos)
+            if ef:
+                e = _tree_map(lambda t: t[cid], ures)
+                p = _tree_map(jnp.add, p, e)
+            c = self._compress_row(p, key, self.up, n, pos)
+            acc = _tree_map(lambda a, cc: a + w[pos] * cc, acc, c)
+            if ef:
+                keep = _tree_map(lambda d, cc, ee: jnp.where(
+                    m[pos] > 0, d - cc, ee), p, c, e)
+                ures = _tree_map(lambda t, v: t.at[cid].set(v), ures, keep)
+            return (acc, ures), None
 
-        return _tree_map(leaf, x_base, rows)
+        acc = _tree_map(jnp.zeros_like, x_base)
+        (acc, ures), _ = jax.lax.scan(client, (acc, ures),
+                                      jnp.arange(n, dtype=jnp.int32))
+        x = _tree_map(lambda xb, a: xb - eta * a / total, x_base, acc)
+        return x, ures
 
     # -- rounds ----------------------------------------------------------
 
@@ -227,32 +286,19 @@ class Reference:
         x_s, ref, dres = self._downlink(x, ref, dres,
                                         jax.random.fold_in(ckey, DOWN_TAG))
         steps, inner = self.local["local_steps"], self.local["inner_batch"]
+        keys = jax.random.split(k_local, self.n_clients)
 
-        def local(cid, k):
+        def local(cid, pos):
             def step(y, ks):
                 qs = jax.random.split(ks, inner)
                 gs = jax.vmap(lambda q: self._grad(data, y, cid, q))(qs)
                 g = _tree_map(lambda a: jnp.mean(a, axis=0), gs)
                 return _tree_map(lambda a, b: a - eta * b, y, g), None
 
-            y, _ = jax.lax.scan(step, x_s, jax.random.split(k, steps))
-            return y
+            y, _ = jax.lax.scan(step, x_s, jax.random.split(keys[pos], steps))
+            return _tree_map(lambda a, b: a - b, y, x_s)
 
-        y = jax.vmap(local)(order, jax.random.split(k_local, self.n_clients))
-        delta = _tree_map(lambda a, b: a - b, y, x_s)
-        m = mask[order]
-        ef = self.up.get("error_feedback", False)
-        if ef:
-            e = _tree_map(lambda t: t[order], ures)
-            delta = _tree_map(jnp.add, delta, e)
-        c = self._compress(delta, ckey, self.up)
-        x_new = self._aggregate(x_s, c, m, -1.0)
-        if ef:
-            keep = _tree_map(
-                lambda d, cc, ee: jnp.where(
-                    m.reshape((-1,) + (1,) * (d.ndim - 1)) > 0, d - cc, ee),
-                delta, c, e)
-            ures = _tree_map(lambda t, v: t.at[order].set(v), ures, keep)
+        x_new, ures = self._uplink(x_s, local, order, mask, ures, ckey, -1.0)
         if self.fault == "frozen":
             x_new = x
         return x_new, ref, dres, ures
@@ -268,24 +314,11 @@ class Reference:
         qkeys = jax.random.split(k_grad, self.n_clients * k).reshape(
             self.n_clients, k, -1)
 
-        def client(cid, ks):
-            gs = jax.vmap(lambda q: self._grad(data, x_b, cid, q))(ks)
+        def client(cid, pos):
+            gs = jax.vmap(lambda q: self._grad(data, x_b, cid, q))(qkeys[pos])
             return _tree_map(lambda a: jnp.mean(a, axis=0), gs)
 
-        g = jax.vmap(client)(order, qkeys)
-        m = mask[order]
-        ef = self.up.get("error_feedback", False)
-        if ef:
-            e = _tree_map(lambda t: t[order], ures)
-            g = _tree_map(jnp.add, g, e)
-        c = self._compress(g, ckey, self.up)
-        x_new = self._aggregate(x, c, m, eta)
-        if ef:
-            keep = _tree_map(
-                lambda d, cc, ee: jnp.where(
-                    m.reshape((-1,) + (1,) * (d.ndim - 1)) > 0, d - cc, ee),
-                g, c, e)
-            ures = _tree_map(lambda t, v: t.at[order].set(v), ures, keep)
+        x_new, ures = self._uplink(x, client, order, mask, ures, ckey, eta)
         if self.fault == "frozen":
             x_new = x
         decay = jnp.clip(1.0 - eta * self.glob["mu_avg"], 0.0, 1.0)
@@ -299,12 +332,14 @@ class Reference:
         n, k = self.n_clients, self.sel_k
         qkeys = jax.random.split(k_vals, n * k).reshape(n, k, -1)
 
-        def value(x):
-            per = jax.vmap(lambda cid, ks: jnp.mean(jax.vmap(
-                lambda q: self._value(data, x, cid, q))(ks)))(order, qkeys)
-            return jnp.mean(per)
+        def values(a):  # one client's mean value of each candidate
+            cid, ks = a
+            return [jnp.mean(jax.vmap(
+                lambda q: self._value(data, x, cid, q))(ks))
+                for x in (anchor, cand)]
 
-        keep = value(anchor) <= value(cand)
+        per = jax.lax.map(values, (order, qkeys))
+        keep = jnp.mean(per[0]) <= jnp.mean(per[1])
         best = _tree_map(lambda a, b: jnp.where(keep, a, b), anchor, cand)
         return best, keep
 
@@ -312,8 +347,9 @@ class Reference:
         counts, values, last_probe, last_mask, t = st
         n = self.n_clients
         pkeys = jax.random.split(jax.random.fold_in(key, PROBE_TAG), n)
-        v = jax.vmap(lambda i, kk: self._value(data, x, i, kk))(
-            jnp.arange(n, dtype=jnp.int32), pkeys).astype(jnp.float32)
+        v = jax.lax.map(lambda a: self._value(data, x, *a),
+                        (jnp.arange(n, dtype=jnp.int32), pkeys)
+                        ).astype(jnp.float32)
         reward = last_probe - v
         cnt = jnp.maximum(counts, 1.0)
         values = jnp.where(last_mask > 0,

@@ -16,6 +16,12 @@ def labels(classes):
     return (classes % 2).astype(jnp.float32)
 
 
+def arrays(data: dict, dtype):
+    """(inputs [N, n, d], targets [N, n]) in the reference's precision."""
+    return (data["features"].astype(dtype),
+            labels(data["classes"]).astype(dtype))
+
+
 def init(config: dict, key, dtype):
     del key
     return jnp.zeros((config["dim"],), dtype)

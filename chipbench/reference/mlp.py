@@ -17,6 +17,12 @@ def labels(classes):
     return classes.astype(jnp.int32)
 
 
+def arrays(data: dict, dtype):
+    """(inputs [N, n, d] in the reference's precision, integer classes
+    [N, n])."""
+    return data["features"].astype(dtype), labels(data["classes"])
+
+
 def init(config: dict, key, dtype):
     arch = config["arch"]
     keys = jax.random.split(key, len(arch) - 1)

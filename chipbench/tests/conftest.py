@@ -35,6 +35,25 @@ def small_cell(name: str, rounds: int = 6):
     return harness.Cell(name, bench, config=c, traffic=t)
 
 
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+
+
+@pytest.fixture
+def test_files(monkeypatch):
+    """Let lookups by name find the test-only files under ``data/<kind>/``
+    (populations, references, families, kernels, metrics) as if they lay in
+    ``chipbench/<kind>/``: what a later configuration brings as new files."""
+    from chipbench import harness
+
+    found = harness._path
+
+    def path(kind, name, ext):
+        extra = os.path.join(DATA, kind, name + ext)
+        return extra if os.path.isfile(extra) else found(kind, name, ext)
+
+    monkeypatch.setattr(harness, "_path", path)
+
+
 @pytest.fixture(scope="session")
 def bench():
     from chipbench import harness

@@ -4,6 +4,8 @@ from __future__ import annotations
 import json
 import os
 
+import pytest
+
 from chipbench import flops, harness, kernel_calls
 
 
@@ -78,3 +80,12 @@ def test_kernel_calls_follow_the_traffic():
                                     "weighted_mean_over_clients"}
     assert sum(n for k, _, _, _, n in mlp
                if k == "weighted_mean_over_clients") == 6 * 49
+
+
+@pytest.mark.parametrize("name, widths", [
+    ("mnist-logreg", [784]),
+    # b0, b1, b2, w0, w1, w2: the 2NN's dict leaves in sorted order
+    ("mnist-2nn", [200, 200, 10, 784 * 200, 200 * 200, 200 * 10]),
+])
+def test_leaf_widths_from_the_parameter_tree(name, widths):
+    assert kernel_calls.leaf_widths(_config(name)) == widths

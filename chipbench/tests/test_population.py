@@ -1,13 +1,39 @@
 """The benchmark's own population has the layout of the program's
 generator (data.synthetic_vision + data.partition.pathological_shards):
 the same shapes, two whole label shards per client, every shard dealt once.
-It is the benchmark's data and need not match the program's bitwise."""
+It is the benchmark's data and need not match the program's bitwise.
+
+It is found by name (``populations/mnist-shards.py``), and the same seed
+gives bitwise the arrays it gave when it was ``chipbench/population.py``."""
 from __future__ import annotations
+
+import hashlib
 
 import jax
 import numpy as np
 
-from chipbench.population import make_population
+from chipbench import harness
+from conftest import small_cell
+
+MNIST_SHARDS = harness.load_module("populations", "mnist-shards")
+make_population = MNIST_SHARDS.make_population
+
+# sha256 over (dtype, shape, bytes) of features then labels, on the CPU,
+# from chipbench/population.py as it stood before the move
+SMALL_DIGEST = ("32ee982d54da68e2472a8ece6ec94259"
+                "f42edcc63a87571761e0e06e6afa156c")
+CELL_DIGEST = ("bb6e5ed67d31b6b6e3ffce3adf4b60cb"
+               "24cc0af7f995bb66f6889e5cdefeb1a0")
+
+
+def _digest(*arrays):
+    h = hashlib.sha256()
+    for a in arrays:
+        a = np.asarray(a)
+        h.update(str(a.dtype).encode())
+        h.update(str(a.shape).encode())
+        h.update(a.tobytes())
+    return h.hexdigest()
 
 
 def _layout(labels, shard):
@@ -62,3 +88,16 @@ def test_same_seed_same_population():
     b = make_population(jax.random.PRNGKey(9), num_clients=10, per_client=20,
                         side=4, classes=10, shards_per_client=2, noise=0.35)
     assert all(np.array_equal(x, y) for x, y in zip(a, b))
+
+
+def test_bitwise_the_population_before_the_move():
+    small = make_population(
+        jax.random.PRNGKey(3), num_clients=20, per_client=60, side=8,
+        classes=10, shards_per_client=2, noise=0.35)
+    assert _digest(*small) == SMALL_DIGEST
+    # through the harness: a configuration that names no population
+    cell = small_cell("logreg.c01-qsgd4ef")
+    assert "population" not in cell.config
+    cell.setup(20261019)
+    assert set(cell.data) == {"features", "classes"}
+    assert _digest(cell.data["features"], cell.data["classes"]) == CELL_DIGEST

@@ -1,6 +1,7 @@
 """Every name in BENCHMARK.json resolves to its file; unknown names fail."""
 from __future__ import annotations
 
+import json
 import os
 
 import pytest
@@ -26,11 +27,20 @@ def test_every_entry_resolves(bench):
 
 @pytest.mark.parametrize("kind, ext", [
     ("configs", ".json"), ("traffic", ".json"), ("limits", ".json"),
-    ("metrics", ".py"), ("kernels", ".py"), ("families", ".py")])
+    ("metrics", ".py"), ("kernels", ".py"), ("families", ".py"),
+    ("populations", ".py"), ("reference", ".py")])
 def test_unknown_name_is_an_error(kind, ext):
     load = harness.load_json if ext == ".json" else harness.load_module
     with pytest.raises(LookupError):
         load(kind, "no-such-name")
+
+
+def test_unknown_population_is_an_error(bench):
+    entry = next(c for c in bench["configs"])
+    with open(os.path.join(harness.ROOT, entry["file"])) as f:
+        config = dict(json.load(f), population="no-such-name")
+    with pytest.raises(LookupError):
+        harness.Cell(bench["workloads"][0]["name"], bench, config=config)
 
 
 def test_unknown_workload_is_an_error(bench):
