@@ -47,7 +47,7 @@ def readings(cell, seed: int, *, control: bool, cache: dict) -> dict:
     for name, kw in kinds.items():
         if name not in cache:
             cache[name] = cell.reference(**kw)
-        cache[name].set_population(cell.data)
+        cache[name].set_population(cell.data, cell.frozen)
         refs[name] = cache[name]
     picks = cell.sample([call], seed)
     for _, c in picks:

@@ -15,8 +15,9 @@ minibatch of one query, R rounds = B1 FedAvg + 1 selection + B2 SGD):
   forward samples each.
 * UCB probe (every row, when the traffic has the policy): N queries of B
   forward samples.
-* Evaluation: the global loss over all N n samples after every row, and
-  once more for the final suboptimality.
+* Evaluation: the global loss over the first ``eval_per_client`` samples
+  of each of the N clients (all n where the configuration names none)
+  after every row, and once more for the final suboptimality.
 """
 from __future__ import annotations
 
@@ -36,5 +37,5 @@ def per_cell_round(config: dict, traffic: dict, family) -> float:
     total += 2 * n * m["selection_k"] * batch * fwd
     if traffic.get("policy"):
         total += (b1 + 1 + b2) * n * batch * fwd
-    total += (b1 + 1 + b2 + 1) * n * per * fwd
+    total += (b1 + 1 + b2 + 1) * n * config.get("eval_per_client", per) * fwd
     return total / rounds

@@ -12,7 +12,16 @@ gives it:
 * ``families/<family>.py``: the program's problem for that family, built
   from that dict, the model's operation counts and, optionally, the Pallas
   kernels its own layers call (``kernel_calls``); ``reference/<family>.py``:
-  its plain loss, parameters and the arrays it takes from the dict;
+  its plain loss, parameters and the arrays it takes from the dict, and,
+  optionally, ``frozen(config, key)``: weights the algorithm does not
+  update (a frozen base under trained adapters), made on the device from
+  the seed in the configuration's dtype, which ``build_problem`` then gets
+  as ``frozen=`` and the reference as an argument of every step;
+* the configuration's optional ``eval_per_client``: how many leading
+  samples of each client the global loss reads (all where it names none).
+  The reference reads that slice; a family whose problem evaluates on
+  fewer samples than a client holds makes the program's global loss read
+  the same slice;
 * ``traffic/<mix>.json``: participation, comm legs, selection policy, grid
   shape, rounds, chips, how many cells the check replays;
 * ``limits/<cell>.json``: the limit of every number the check compares;
@@ -44,6 +53,7 @@ BENCHMARK = os.path.join(ROOT, "BENCHMARK.json")
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
 DEFAULT_POPULATION = "mnist-shards"
+FROZEN_TAG = 0xF207E  # folded into the data key for the frozen weights
 
 
 # -- lookup by name ----------------------------------------------------------
@@ -156,7 +166,8 @@ class Cell:
     # -- program side ------------------------------------------------------
 
     def setup(self, seed: int):
-        """Population, weights, problem, method and mesh from ``seed``."""
+        """Population, weights (frozen ones too, where the reference makes
+        them), problem, method and mesh from ``seed``."""
         import jax
         import jax.numpy as jnp
 
@@ -169,8 +180,14 @@ class Cell:
         k_pop, k_init = jax.random.split(key)
         self.data = self.population.make(self.config, k_pop)
         self.x0 = self.model.init(self.config, k_init, jnp.float32)
+        self.frozen = None
+        extra = {}
+        if hasattr(self.model, "frozen"):
+            self.frozen = self.model.frozen(
+                self.config, jax.random.fold_in(key, FROZEN_TAG))
+            extra["frozen"] = self.frozen
         self.problem = self.family.build_problem(self.config, self.data,
-                                                 self.x0)
+                                                 self.x0, **extra)
         self.x0 = self.problem.x0
         m = self.config["method"]
         loc, glob = m["local"], m["global"]
@@ -256,6 +273,7 @@ class Cell:
         from chipbench.reference.fedchain import Reference
 
         return Reference(self.model, self.config, self.traffic, self.data,
+                         frozen=self.frozen,
                          dtype=jnp.float32 if dtype is None else dtype,
                          precision=precision, fault=fault)
 

@@ -12,13 +12,21 @@ local steps, SGD's client gradients, the selection values, the UCB probe,
 the global loss) is a sequential map over the clients, and a round's uplink
 is one scan over the clients in the round's order: each client's row is
 made, compressed and added to the server's sum before the next client's.
-So at a model's width the reference keeps one client's parameters,
-gradients and activations, besides the per-client feedback residuals that
-the algorithm itself keeps. The queries within one client stay vectorised.
+SGD's K queries are a running sum in key order and the selection values a
+sequential map, one query at a time; FedAvg's inner batch of queries stays
+vectorised. So at a model's width the reference keeps one client's
+parameters and one query's gradient and activations (``inner_batch`` of
+them in a FedAvg step), besides the per-client feedback residuals that the
+algorithm itself keeps.
 
 The data comes from the model module: ``model.arrays(data, dtype)`` turns
 the population's dict into (inputs, targets), each [N, n, ...]; integer
-arrays such as token ids stay integers.
+arrays such as token ids stay integers. A model whose configuration brings
+weights the algorithm does not update (a frozen base under trained
+adapters) defines ``frozen(config, key)``; the reference is given that
+pytree, casts its floats to its own precision once and passes it to every
+jitted step as an argument, behind the data, never as a constant; the
+model's loss then takes it last: ``model.loss(params, X, y, l2, frozen)``.
 
 Per cell (seed s, stepsize multiplier m, mask seed, selection seed, fold f):
 
@@ -51,9 +59,10 @@ Per cell (seed s, stepsize multiplier m, mask seed, selection seed, fold f):
   keep e_i = d_i - c_i. Without: x = mean over participants of
   (x_s + c_i).
 * SGD round: gradients at the downlink reconstruction, K queries a client
-  (keys split(k_work, N K) in client-major order), uplinked as g_i (+ e_i
-  with feedback); x = x - eta * mean over participants of C(.). The output
-  is the (1 - eta mu)^-r weighted average of the iterates, or the last.
+  (keys split(k_work, N K) in client-major order), their mean uplinked as
+  g_i (+ e_i with feedback); x = x - eta * mean over participants of C(.).
+  The output is the (1 - eta mu)^-r weighted average of the iterates, or
+  the last.
 * A minibatch query: idx = randint(key, (B,), 0, n); the loss of the
   client's rows idx; gradient by autodiff.
 * Selection row: candidates x0 and the FedAvg iterate; k_sample, k_vals =
@@ -67,12 +76,14 @@ Per cell (seed s, stepsize multiplier m, mask seed, selection seed, fold f):
   = last probe - v; running mean over their counts; score = mean +
   c sqrt(log(t + 1) / max(count, 1)), never-chosen clients first; the S
   highest scores participate (ties to the lower index).
-* History: the global loss (mean over clients of the client's full-data
-  loss) of the active stage's output after each row; the selection row
-  records the winner's. Bits follow the closed forms: uplink
-  S_r (32 + d (b + 1)) per QSGD leaf (32 d uncompressed), the same for the
-  downlink, 2 * 32 N up and 2 * 32 D N down on the selection row, and
-  32 N up for each UCB probe.
+* History: the global loss of the active stage's output after each row:
+  the mean over clients of the loss over the client's first
+  ``eval_per_client`` samples (the configuration's key; all n where it
+  names none), taken in blocks of B samples, a last short block weighted
+  by its size; the selection row records the winner's. Bits follow the
+  closed forms: uplink S_r (32 + d (b + 1)) per QSGD leaf (32 d
+  uncompressed), the same for the downlink, 2 * 32 N up and 2 * 32 D N
+  down on the selection row, and 32 N up for each UCB probe.
 
 ``dtype`` sets the precision of every float array and ``precision`` that
 of the matrix products: float32 at ``highest`` for the reference; the
@@ -140,7 +151,7 @@ class Reference:
     """Runs cells of one configuration under one traffic mix."""
 
     def __init__(self, model, config: dict, traffic: dict, data: dict, *,
-                 dtype=jnp.float32, precision: str = "highest",
+                 frozen=None, dtype=jnp.float32, precision: str = "highest",
                  fault: str | None = None):
         self.model = model
         self.cfg = config
@@ -148,7 +159,7 @@ class Reference:
         self.dtype = jnp.dtype(dtype)
         self.precision = precision
         self.fault = fault
-        self.set_population(data)
+        self.set_population(data, frozen)
         self.batch = model.batch(config)
         self.l2 = float(config["l2"])
         m = config["method"]
@@ -163,17 +174,27 @@ class Reference:
         self.policy = traffic.get("policy")
         self._jit()
 
-    def set_population(self, data: dict):
+    def set_population(self, data: dict, frozen=None):
         """The data every jitted step takes as an argument (never as a
         constant, so a new population of the same shape compiles nothing):
-        the model's (inputs, targets), each [N, n, ...]."""
+        the model's (inputs, targets), each [N, n, ...], and behind them
+        the frozen weights where the configuration brings them, their
+        floats cast to the reference's precision."""
         self.data = tuple(self.model.arrays(data, self.dtype))
         self.n_clients, self.n_per = self.data[0].shape[:2]
+        if frozen is not None:
+            self.data += (jax.tree.map(
+                lambda l: l.astype(self.dtype)
+                if jnp.issubdtype(l.dtype, jnp.floating) else l, frozen),)
+        self.n_eval = int(self.cfg.get("eval_per_client", self.n_per))
+        if not 0 < self.n_eval <= self.n_per:
+            raise ValueError(f"eval_per_client {self.n_eval} is not in 1.."
+                             f"{self.n_per}, the samples a client holds")
 
     # -- building blocks -------------------------------------------------
 
-    def _loss(self, x, X, y):
-        return self.model.loss(x, X, y, self.l2)
+    def _loss(self, data, x, X, y):
+        return self.model.loss(x, X, y, self.l2, *data[2:])
 
     def _query(self, data, cid, key):
         idx = jax.random.randint(key, (self.batch,), 0, self.n_per)
@@ -181,15 +202,40 @@ class Reference:
 
     def _grad(self, data, x, cid, key):
         X, y = self._query(data, cid, key)
-        return jax.grad(self._loss)(x, X, y)
+        return jax.grad(self._loss, argnums=1)(data, x, X, y)
+
+    def _mean_grad(self, data, x, cid, keys):
+        """Mean of the minibatch gradients of ``keys``, one query at a
+        time: a running sum in key order, over their number."""
+        def add(acc, key):
+            return _tree_map(jnp.add, acc, self._grad(data, x, cid, key)), None
+
+        total, _ = jax.lax.scan(add, self._grad(data, x, cid, keys[0]),
+                                keys[1:])
+        return _tree_map(lambda t: t / keys.shape[0], total)
 
     def _value(self, data, x, cid, key):
         X, y = self._query(data, cid, key)
-        return self._loss(x, X, y)
+        return self._loss(data, x, X, y)
 
     def _global_loss(self, data, x):
-        per = jax.lax.map(lambda d: self._loss(x, *d), data)
-        return jnp.mean(per)
+        """Mean over clients of the loss over each client's first
+        ``n_eval`` samples, in blocks of ``batch``: one block's
+        activations at a time."""
+        full, rest = divmod(self.n_eval, self.batch)
+
+        def client(shard):
+            blocks = _tree_map(lambda a: a[:full * self.batch].reshape(
+                (full, self.batch) + a.shape[1:]), shard)
+            total = self.batch * jnp.sum(jax.lax.map(
+                lambda blk: self._loss(data, x, *blk), blocks))
+            if rest:
+                tail = _tree_map(lambda a: a[full * self.batch:self.n_eval],
+                                 shard)
+                total = total + rest * self._loss(data, x, *tail)
+            return total / self.n_eval
+
+        return jnp.mean(jax.lax.map(client, data[:2]))
 
     def _order(self, key):
         """Fisher-Yates over all N clients."""
@@ -290,6 +336,10 @@ class Reference:
 
         def local(cid, pos):
             def step(y, ks):
+                # the inner batch stays vectorised: computed in turn, a lone
+                # query's matrix-vector product fuses with the regulariser's
+                # add on XLA:CPU, and the logreg replay leaves the recorded
+                # vectorised numbers by more than rounding allows for
                 qs = jax.random.split(ks, inner)
                 gs = jax.vmap(lambda q: self._grad(data, y, cid, q))(qs)
                 g = _tree_map(lambda a: jnp.mean(a, axis=0), gs)
@@ -315,8 +365,7 @@ class Reference:
             self.n_clients, k, -1)
 
         def client(cid, pos):
-            gs = jax.vmap(lambda q: self._grad(data, x_b, cid, q))(qkeys[pos])
-            return _tree_map(lambda a: jnp.mean(a, axis=0), gs)
+            return self._mean_grad(data, x_b, cid, qkeys[pos])
 
         x_new, ures = self._uplink(x, client, order, mask, ures, ckey, eta)
         if self.fault == "frozen":
@@ -334,9 +383,9 @@ class Reference:
 
         def values(a):  # one client's mean value of each candidate
             cid, ks = a
-            return [jnp.mean(jax.vmap(
-                lambda q: self._value(data, x, cid, q))(ks))
-                for x in (anchor, cand)]
+            per = jax.lax.map(lambda q: [self._value(data, x, cid, q)
+                                         for x in (anchor, cand)], ks)
+            return [jnp.mean(v) for v in per]
 
         per = jax.lax.map(values, (order, qkeys))
         keep = jnp.mean(per[0]) <= jnp.mean(per[1])

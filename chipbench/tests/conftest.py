@@ -38,20 +38,24 @@ def small_cell(name: str, rounds: int = 6):
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 
 
-@pytest.fixture
-def test_files(monkeypatch):
-    """Let lookups by name find the test-only files under ``data/<kind>/``
-    (populations, references, families, kernels, metrics) as if they lay in
-    ``chipbench/<kind>/``: what a later configuration brings as new files."""
-    from chipbench import harness
-
-    found = harness._path
-
+def lookup_with_test_data(found):
+    """``harness._path`` that finds the test-only files under
+    ``data/<kind>/`` (populations, references, families, kernels, metrics)
+    as if they lay in ``chipbench/<kind>/``, and the rest with ``found``."""
     def path(kind, name, ext):
         extra = os.path.join(DATA, kind, name + ext)
         return extra if os.path.isfile(extra) else found(kind, name, ext)
 
-    monkeypatch.setattr(harness, "_path", path)
+    return path
+
+
+@pytest.fixture
+def test_files(monkeypatch):
+    """Let lookups by name find the test-only files: what a later
+    configuration brings as new files."""
+    from chipbench import harness
+
+    monkeypatch.setattr(harness, "_path", lookup_with_test_data(harness._path))
 
 
 @pytest.fixture(scope="session")

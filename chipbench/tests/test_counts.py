@@ -24,15 +24,20 @@ def test_2nn_forward_and_gradient():
     assert sum(kernel_calls.leaf_widths(cfg)) == cfg["params"] == 199_210
 
 
-def test_logreg_per_cell_round():
+@pytest.mark.parametrize("eval_per_client", [None, 60])
+def test_logreg_per_cell_round(eval_per_client):
+    """The evaluation reads every client's first ``eval_per_client``
+    samples, all 600 where the configuration names none."""
     fam = harness.load_module("families", "logreg")
     cfg = _config("mnist-logreg")
+    if eval_per_client is not None:
+        cfg["eval_per_client"] = eval_per_client
     traffic = harness.load_json("traffic", "c01-qsgd4ef")
     d = 784
     fedavg = 25 * 10 * 4 * 4 * 6 * 4 * d   # rounds x S x steps x queries x B
     sgd = 24 * 10 * 16 * 6 * 4 * d
     select = 2 * 100 * 16 * 6 * 2 * d
-    evals = 51 * 60_000 * 2 * d              # 50 rows + the final one
+    evals = 51 * 100 * (eval_per_client or 600) * 2 * d  # 50 rows + final
     want = (fedavg + sgd + select + evals) / 50
     assert flops.per_cell_round(cfg, traffic, fam) == want
 

@@ -39,29 +39,62 @@ def test_matches_the_vectorised_reference():
                                    rtol=1e-6, atol=0)
 
 
-def _fedavg_temp_bytes(n):
-    """Temp bytes of one compiled FedAvg round (QSGD-4 + EF up) of the 2NN
-    reference at 8-512-512-8: a parameter copy (1.09 MB) dominates a
-    client's memory, not its data."""
+def _reference_2nn(n, k=5):
+    """The 2NN reference at 8-512-512-8 (a parameter copy is 1.09 MB), N
+    clients of 8 samples, SGD taking ``k`` queries a client, its start."""
     model = harness.load_module("reference", "mlp")
-    cfg = dict(harness.load_json("configs", "mnist-2nn"), num_clients=n,
-               per_client=8, arch=[8, 512, 512, 8])
+    cfg = harness.load_json("configs", "mnist-2nn")
+    method = dict(cfg["method"], **{"global": dict(cfg["method"]["global"],
+                                                   k=k)})
+    cfg = dict(cfg, num_clients=n, per_client=8, arch=[8, 512, 512, 8],
+               method=method)
     data = {"features": jnp.zeros((n, 8, 8), jnp.float32),
             "classes": jnp.zeros((n, 8), jnp.int32)}
     ref = Reference(model, cfg, harness.load_json("traffic", "c01-qsgd4ef"),
                     data)
-    x = model.init(cfg, jax.random.PRNGKey(0), jnp.float32)
-    zero = jax.tree.map(jnp.zeros_like, x)
-    ures = jax.tree.map(lambda l: jnp.zeros((n,) + l.shape), x)
-    mem = ref.j_fedavg.lower(
-        ref.data, x, zero, zero, ures, jax.random.PRNGKey(1),
-        jnp.ones((n,), jnp.float32), jnp.float32(0.1)).compile(
-        ).memory_analysis()
+    return ref, model.init(cfg, jax.random.PRNGKey(0), jnp.float32)
+
+
+def _temp_bytes(step, *args):
+    mem = step.lower(*args).compile().memory_analysis()
     if mem is None:
         pytest.skip("the backend reports no memory analysis")
     return mem.temp_size_in_bytes
 
 
+def _round_operands(ref, x):
+    """Zero downlink reference and residual, zero uplink residuals, a key,
+    every client in, stepsize 0.1."""
+    zero = jax.tree.map(jnp.zeros_like, x)
+    ures = jax.tree.map(lambda l: jnp.zeros((ref.n_clients,) + l.shape), x)
+    return (zero, zero, ures, jax.random.PRNGKey(1),
+            jnp.ones((ref.n_clients,), jnp.float32), jnp.float32(0.1))
+
+
+def _fedavg_temp_bytes(n):
+    """Temp bytes of one compiled FedAvg round (QSGD-4 + EF up) of the 2NN
+    reference: a parameter copy dominates a client's memory, not its
+    data."""
+    ref, x = _reference_2nn(n)
+    return _temp_bytes(ref.j_fedavg, ref.data, x, *_round_operands(ref, x))
+
+
+def _sgd_temp_bytes(k):
+    """Temp bytes of one compiled SGD round (QSGD-4 + EF up) of the 2NN
+    reference at 4 clients, ``k`` gradient queries a client."""
+    ref, x = _reference_2nn(4, k)
+    return _temp_bytes(ref.j_sgd, ref.data, x, x, jnp.float32(1.0),
+                       *_round_operands(ref, x))
+
+
 def test_fedavg_round_holds_one_client_at_a_time():
     small, large = _fedavg_temp_bytes(4), _fedavg_temp_bytes(32)
     assert large < 2 * small, (small, large)
+
+
+def test_sgd_round_holds_one_query_at_a_time():
+    """SGD's K queries are summed one at a time: with each query's
+    parameter-sized gradient kept until the mean, 16 queries would hold 16
+    copies (1.09 MB each) besides a round's 5.4 MB."""
+    one, many = _sgd_temp_bytes(1), _sgd_temp_bytes(16)
+    assert many < 2 * one, (one, many)
