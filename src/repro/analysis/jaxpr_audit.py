@@ -26,6 +26,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jax_core
 
 from repro.analysis import CONST_BYTE_CEILING
 from repro.core import runner
@@ -168,7 +169,7 @@ def collect_executor_records(only: Optional[Sequence[str]] = None
 
 
 def _sub_jaxprs(value):
-    if isinstance(value, jax.core.ClosedJaxpr):
+    if isinstance(value, jax_core.ClosedJaxpr):
         yield value
     elif isinstance(value, (tuple, list)):
         for v in value:

@@ -167,6 +167,54 @@ def sample_clients(key, num_clients: int, s: int):
     return idx[:s]
 
 
+def participant_block(num_clients: int) -> int:
+    """B, the client rows one block of ``participant_rows`` computes: a
+    static function of N alone, ⌈N/8⌉ (13 at N = 100)."""
+    return -(-num_clients // 8)
+
+
+def local_rows(num_clients: int, cohort: int) -> int:
+    """Rows ``participant_rows`` computes a round for ``cohort``
+    participants of ``num_clients``: whole blocks of B."""
+    b = participant_block(num_clients)
+    return b * -(-cohort // b)
+
+
+def participant_rows(fn, cids, keys, mask, fill):
+    """``fn(cids, keys)`` for the round's participants only, in blocks.
+
+    ``fn`` maps [B] client ids and their [B, ...] keys to a pytree of [B, ...]
+    rows. ``cids`` [N] is the round's permutation, ``keys`` its per-position
+    keys and ``mask`` [N] the participation mask by client id. Positions
+    whose client has a nonzero mask come first (a stable sort, so in their
+    permutation order) and are computed ⌈P/B⌉ blocks at a time, P read from
+    the mask at run time, so participation never changes a shape. Each
+    position keeps its key, so every participant's row is what computing
+    all N rows would give it; the other rows hold ``fill`` (one row, a
+    pytree like ``fn``'s rows) unless a block's tail reaches them, and carry
+    no signal: the comm layer gives them weight 0 and keeps their residual.
+    Returns the [N, ...] rows in permutation order.
+    """
+    n = cids.shape[0]
+    b = participant_block(n)
+    part = mask[cids] != 0
+    order = jnp.argsort((~part).astype(jnp.int32), stable=True)
+    n_blocks = (jnp.sum(part, dtype=jnp.int32) + (b - 1)) // b
+    rows0 = jax.tree.map(lambda l: jnp.broadcast_to(l, (n,) + l.shape), fill)
+
+    def body(carry):
+        i, rows = carry
+        # the last block may be clamped back into range: its first rows are
+        # then computed again, to the same values
+        pos = jax.lax.dynamic_slice(order, (i * b,), (b,))
+        out = fn(cids[pos], keys[pos])
+        return i + 1, jax.tree.map(lambda t, v: t.at[pos].set(v), rows, out)
+
+    _, rows = jax.lax.while_loop(lambda c: c[0] < n_blocks, body,
+                                 (jnp.int32(0), rows0))
+    return rows
+
+
 @scope("local")
 def grad_k(problem, x, client_ids, key, k: int, *, keys=None):
     """Algo 7 ``Grad``: per-client average of K stochastic gradients at x.

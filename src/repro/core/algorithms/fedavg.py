@@ -81,9 +81,15 @@ class FedAvg(base.FederatedAlgorithm):
             x_start, comm = comm_lib.downlink(
                 comm, state.x, comm_lib.downlink_key(key))
         with scope("local"):
-            y_final = jax.vmap(
+            local = jax.vmap(
                 lambda cid, kk: self._local(problem, x_start, cid, kk,
-                                            state.eta))(cids, keys)
+                                            state.eta))
+            if comm is None:
+                y_final = local(cids, keys)
+            else:
+                # participants only; the others stay at x_start (delta 0)
+                y_final = base.participant_rows(local, cids, keys,
+                                                comm.mask, x_start)
         if comm is not None:
             from repro.kernels.aggregate import ops as agg_ops
 

@@ -13,8 +13,9 @@ runs through the fused Pallas aggregation kernel (``kernels.aggregate.ops``):
 η is folded into the client weights (η/S each) so the traced stepsize reaches
 the kernel as data while ``lr`` stays static.
 
-Comm-aware: with a ``comm`` leaf injected (``repro.comm``), every client's
-K-sample gradient is computed, the uplink is compressed (g is the wire
+Comm-aware: with a ``comm`` leaf injected (``repro.comm``), each of the
+round's participants computes its K-sample gradient
+(``base.participant_rows``), the uplink is compressed (g is the wire
 payload), and the server step averages over the round's participation mask.
 With the identity compressor and full participation this path is bit-exact
 with the plain one.
@@ -63,8 +64,8 @@ class SGD(base.FederatedAlgorithm):
             from repro.comm import config as comm_cfg
             from repro.kernels.aggregate import ops as agg_ops
 
-            # all N clients compute (static shape); the round's mask decides
-            # who transmits — an algorithm-level s would be silently ignored
+            # the round's mask decides who computes and transmits — an
+            # algorithm-level s would be silently ignored
             comm_cfg.reject_algo_participation(self.s, self.name)
             n = problem.num_clients
             cids = base.sample_clients(k_sample, n, n)
@@ -73,7 +74,14 @@ class SGD(base.FederatedAlgorithm):
             # identity downlink); the server step stays at the exact iterate
             x_b, comm = comm_lib.downlink(
                 comm, state.x, comm_lib.downlink_key(key))
-            g_per = base.grad_k(problem, x_b, cids, k_grad, self.k)
+            # participants only, each with the key of its position; the
+            # others send a zero gradient
+            keys = jax.random.split(k_grad, n * self.k).reshape(n, self.k, -1)
+            with scope("local"):
+                g_per = base.participant_rows(
+                    lambda c, ks: base.grad_k(problem, x_b, c, None, self.k,
+                                              keys=ks),
+                    cids, keys, comm.mask, tm.tree_zeros_like(x_b))
             if comm_cfg.ef_enabled(comm) and agg_ops.use_fused_aggregate():
                 # one fused kernel pass: masked weighted mean + EF residual
                 # update + server step — bitwise identical to the unfused

@@ -126,7 +126,17 @@ import numpy as np
 from repro.core import chain as chain_lib
 from repro.core import runner as runner_lib
 from repro.core import tree_math as tm
+from repro.core.algorithms import base as algo_base
 from repro.obs.profile import annotate
+
+
+def masks_span(num_clients: int, cohort: int):
+    """The ``repro.sweep.masks`` host span. Its args say how far the
+    participants-only local work engages: ``cohort`` participants a round
+    and the ``local_rows`` FedAvg's and SGD's ``local`` compute for them
+    (whole blocks, ``algorithms.base.participant_rows``)."""
+    return annotate("repro.sweep.masks", cohort=cohort,
+                    local_rows=algo_base.local_rows(num_clients, cohort))
 
 
 @dataclasses.dataclass
@@ -838,7 +848,7 @@ def _run_grid_sweep(algo_or_chain, problem, x0, rounds: int, *,
             comm0 = comm.init_state(n_clients, x0_one)
 
     if comm is not None:
-        with annotate("repro.sweep.masks"):
+        with masks_span(n_clients, comm.clients_per_round(n_clients)):
             n_sched = (algo_or_chain.schedule_len(rounds) if is_chain
                        else rounds)
             # one independent [R, N] schedule per (problem, seed) cell:

@@ -237,7 +237,8 @@ def run_sweep_sharded(algo_or_chain, problem, x0, rounds: int, *,
                 n_clients, tm.tree_index(x0_stack, 0) if per_cell else x0)
 
     if comm is not None:
-        with annotate("repro.sweep.masks"):
+        with sweep_lib.masks_span(n_clients,
+                                  comm.clients_per_round(n_clients)):
             n_sched = (algo_or_chain.schedule_len(rounds) if is_chain
                        else rounds)
             # per-cell [R, N] schedules; fold p·S + s == s when there is no

@@ -167,7 +167,8 @@ def selection_grid_operands(algo_or_chain, problem, x0, rounds: int, *,
                      else None)
         comm0 = comm.init_state(n_clients, tm.tree_index(x0_stack, 0))
 
-    with annotate("repro.sweep.masks"):
+    cohort = max(q.clients_per_round(n_clients) for q in policies)
+    with sweep_lib.masks_span(n_clients, cohort):
         n_sched = (algo_or_chain.schedule_len(rounds) if is_chain
                    else rounds)
         # selection keys: policy-INDEPENDENT fold p·S + s, so every policy
